@@ -9,8 +9,9 @@ Modes (the CI bench-smoke step runs ``--quick --mode both``):
   single   the nprobe sweep (per-query and query-grouped scan layouts) plus
            the graph-search baseline; pins recall@10 = 1.0 at ~0.4% scanned
            (nprobe=1 on the quick synth workload — the PR 1 pin);
-  sharded  4 forced-host-device ``core.distributed.ShardedIvf`` serving in a
-           child process (``benchmarks.common.run_forced_host_child``):
+  sharded  ``core.distributed.ShardedIvf`` serving on the real devices, or
+           on 4 forced host devices in a CPU child
+           (``benchmarks.common.run_sharded_mode``):
            bit-exact parity with single-device search and exactly 1
            transfer-guard-verified host sync per query batch (f32 AND
            codec'd rerank=0 search);
@@ -255,7 +256,8 @@ def _sharded_child(quick: bool):
     res = gk_means(X, k, kappa=16, xi=64, tau=3, iters=6,
                    key=jax.random.PRNGKey(1))
     index = ivf.build_ivf(X, res, block_rows=64)
-    mesh = jax.make_mesh((R,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(R)
     sivf = ShardedIvf(mesh, index)
 
     i1, d1 = jax.device_get(ivf.search(index, q, topk=topk, nprobe=nprobe))
@@ -325,14 +327,14 @@ def _sharded_child(quick: bool):
 
 
 def run_sharded(quick: bool = True, devices: int = SHARDED_DEVICES):
-    """Sharded mode via a child process with forced host devices (the parent
-    JAX runtime is already initialised with the real device count)."""
+    """Sharded mode: in-process on the real devices, or a forced-host-device
+    CPU rehearsal (``benchmarks.common.run_sharded_mode``)."""
     try:
-        from benchmarks.common import run_forced_host_child
+        from benchmarks.common import run_sharded_mode
     except ImportError:       # run directly: benchmarks/ itself is sys.path
-        from common import run_forced_host_child
+        from common import run_sharded_mode
     from repro.obs import load_records
-    run_forced_host_child(__file__, quick, devices)
+    run_sharded_mode(__file__, _sharded_child, quick, devices)
     rec = load_records(SHARDED_JSON)[0]
     m = rec["metrics"]
     scan_frac = rec.get("telemetry", {}).get("scan_frac", [-1.0])[0]
@@ -361,6 +363,8 @@ def main():
                     choices=["single", "sharded", "pq", "both"])
     ap.add_argument("--child", action="store_true", help=argparse.SUPPRESS)
     args = ap.parse_args()
+    from repro.launch import runtime
+    runtime.init()
     if args.child:
         _sharded_child(args.quick)
         return

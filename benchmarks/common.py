@@ -79,22 +79,47 @@ def run_forced_host_child(bench_file: str, quick: bool, devices: int,
                           extra: Tuple[str, ...] = ()) -> None:
     """Re-run `bench_file --child` under R forced host CPU devices.
 
-    The parent JAX runtime is already initialised with the real device
-    count, so multi-device CPU benches execute their measurement body in a
-    child process with ``--xla_force_host_platform_device_count`` set
-    (engine_bench and graph_build_bench share this launch recipe).
+    A CPU rehearsal of a sharded mode: the child sees ``devices`` virtual
+    CPU devices (``--xla_force_host_platform_device_count``).  It runs only
+    where JAX is pinned to the CPU (``JAX_PLATFORMS=cpu``): on a machine
+    with an accelerator the sharded modes run in-process on the real
+    devices (``run_sharded_mode``), since a child would measure CPU devices
+    — or, given the chip, find it held by the parent.
     """
     import os
     import subprocess
     import sys
+    if not cpu_pinned():
+        raise RuntimeError(
+            "run_forced_host_child is a CPU rehearsal and needs "
+            "JAX_PLATFORMS=cpu; on an accelerator run the sharded mode "
+            "in-process on the real devices")
     here = os.path.dirname(os.path.abspath(bench_file))
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + f" --xla_force_host_platform_device_count={devices}")
-    env["JAX_PLATFORMS"] = "cpu"   # forced host devices are a CPU feature
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(here, "..", "src")]
         + ([env["PYTHONPATH"]] if env.get("PYTHONPATH") else []))
     cmd = [sys.executable, os.path.abspath(bench_file), "--child",
            "--quick" if quick else "--full", *extra]
     subprocess.run(cmd, check=True, env=env, timeout=timeout)
+
+
+def cpu_pinned() -> bool:
+    """Whether JAX is pinned to the CPU — read from the environment, so the
+    answer initialises no backend."""
+    import os
+    return os.environ.get("JAX_PLATFORMS", "") == "cpu"
+
+
+def run_sharded_mode(bench_file: str, body: Callable[[bool], None],
+                     quick: bool, devices: int,
+                     extra: Tuple[str, ...] = ()) -> None:
+    """Run a sharded bench mode's ``body(quick)``: in-process on the real
+    devices, or — where JAX is pinned to the CPU — rehearsed on ``devices``
+    forced host devices in a child (``run_forced_host_child``)."""
+    if cpu_pinned():
+        run_forced_host_child(bench_file, quick, devices, extra=extra)
+    else:
+        body(quick)

@@ -16,9 +16,10 @@ Three modes:
            (emits ``BENCH_engine.json``);
   sharded  the same comparison across a mesh: ``ShardedEngine.run`` vs a
            host-driven loop of ``ShardedEngine.epoch`` + per-epoch
-           ``ShardedEngine.distortion`` syncs.  Runs in a child process with
-           ``--xla_force_host_platform_device_count`` so it works on a
-           single-CPU box (emits ``BENCH_sharded_run.json``);
+           ``ShardedEngine.distortion`` syncs, on the real devices — or,
+           with ``JAX_PLATFORMS=cpu``, in a child process with
+           ``--xla_force_host_platform_device_count`` (emits
+           ``BENCH_sharded_run.json``);
   scale    a large-k ``ShardedEngine.run``: the probe-candidate centroid
            exchange instead of a replicated (k, d) matrix.  Reports the
            per-shard peak candidate-set size (static by construction — the
@@ -144,7 +145,8 @@ def _sharded_child(quick: bool):
     a0 = two_means_tree(X, k, key)
     st = engine.init_state(X, a0, k)
 
-    mesh = jax.make_mesh((R,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(R)
     cfg = engine.EngineConfig(batch_size=bs, iters=iters, min_move_frac=-1.0,
                               telemetry=True)
     eng = ShardedEngine(mesh, cfg)
@@ -226,7 +228,8 @@ def _scale_child(quick: bool):
     G = jnp.maximum(random_graph(key, n, kappa), 0)
     st = engine.init_state(X, two_means_tree(X, k, key), k)
 
-    mesh = jax.make_mesh((R,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(R)
     cfg = engine.EngineConfig(batch_size=bs, iters=iters, min_move_frac=-1.0)
     eng = ShardedEngine(mesh, cfg, kind="graph")
     jax.block_until_ready(eng.run(X, G, st.assign, st.D, st.cnt, key)[0])
@@ -258,13 +261,14 @@ def _scale_child(quick: bool):
 
 
 def run_scale(quick: bool = True, devices: int = SHARDED_DEVICES):
-    """Scale mode via a forced-host-device child (see ``_scale_child``)."""
+    """Scale mode (``_scale_child``) via ``common.run_sharded_mode``."""
     try:
-        from benchmarks.common import run_forced_host_child
+        from benchmarks.common import run_sharded_mode
     except ImportError:
-        from common import run_forced_host_child
+        from common import run_sharded_mode
     from repro.obs import load_records
-    run_forced_host_child(__file__, quick, devices, extra=("--kind", "scale"))
+    run_sharded_mode(__file__, _scale_child, quick, devices,
+                     extra=("--kind", "scale"))
     rec = load_records(SCALE_JSON)[0]
     m = rec["metrics"]
     return [
@@ -276,14 +280,14 @@ def run_scale(quick: bool = True, devices: int = SHARDED_DEVICES):
 
 
 def run_sharded(quick: bool = True, devices: int = SHARDED_DEVICES):
-    """Sharded mode via a child process with forced host devices (the parent
-    JAX runtime is already initialised with the real device count)."""
+    """Sharded mode: in-process on the real devices, or a forced-host-device
+    CPU rehearsal (``benchmarks.common.run_sharded_mode``)."""
     try:
-        from benchmarks.common import run_forced_host_child
+        from benchmarks.common import run_sharded_mode
     except ImportError:       # run directly: benchmarks/ itself is sys.path
-        from common import run_forced_host_child
+        from common import run_sharded_mode
     from repro.obs import load_records
-    run_forced_host_child(__file__, quick, devices)
+    run_sharded_mode(__file__, _sharded_child, quick, devices)
     rec = load_records(SHARDED_JSON)[0]
     m, R = rec["metrics"], rec["shapes"]["devices"]
     return [
@@ -313,6 +317,8 @@ def main():
     ap.add_argument("--kind", default="sharded",
                     choices=["sharded", "scale"], help=argparse.SUPPRESS)
     args = ap.parse_args()
+    from repro.launch import runtime
+    runtime.init()
     quick = args.quick
     if args.child:
         (_scale_child if args.kind == "scale" else _sharded_child)(quick)

@@ -181,7 +181,8 @@ def _sharded_child(quick: bool):
     X = gmm_blobs(key, n, d, 256)
     cfg = GraphBuildConfig(kappa=kappa, xi=xi, tau=tau, shards=R,
                            telemetry=True)
-    mesh = jax.make_mesh((R,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(R)
     builder = GraphBuilder(cfg, mesh=mesh)
 
     g1, d1 = jax.device_get(build_graph(X, key, cfg))   # R-way emulation
@@ -251,7 +252,8 @@ def _scale_child(quick: bool):
     cfg = GraphBuildConfig(kappa=kappa, xi=xi, tau=tau, shards=R)
     k0, n_pad = _plan(n, cfg)
     cap = cfg.cap_factor * xi
-    mesh = jax.make_mesh((R,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(R)
     builder = GraphBuilder(cfg, mesh=mesh)
     jax.block_until_ready(builder.build(X, key)[0].ids)   # warm
 
@@ -284,13 +286,14 @@ def _scale_child(quick: bool):
 
 
 def run_scale(quick: bool = True, devices: int = SHARDED_DEVICES):
-    """Scale mode via a forced-host-device child (see ``_scale_child``)."""
+    """Scale mode (``_scale_child``) via ``common.run_sharded_mode``."""
     try:
-        from benchmarks.common import run_forced_host_child
+        from benchmarks.common import run_sharded_mode
     except ImportError:
-        from common import run_forced_host_child
+        from common import run_sharded_mode
     from repro.obs import load_records
-    run_forced_host_child(__file__, quick, devices, extra=("--kind", "scale"))
+    run_sharded_mode(__file__, _scale_child, quick, devices,
+                     extra=("--kind", "scale"))
     rec = load_records(SCALE_JSON)[0]
     m = rec["metrics"]
     return [
@@ -305,14 +308,14 @@ def run_scale(quick: bool = True, devices: int = SHARDED_DEVICES):
 
 
 def run_sharded(quick: bool = True, devices: int = SHARDED_DEVICES):
-    """Sharded mode via a child process with forced host devices (the parent
-    JAX runtime is already initialised with the real device count)."""
+    """Sharded mode: in-process on the real devices, or a forced-host-device
+    CPU rehearsal (``benchmarks.common.run_sharded_mode``)."""
     try:
-        from benchmarks.common import run_forced_host_child
+        from benchmarks.common import run_sharded_mode
     except ImportError:       # run directly: benchmarks/ itself is sys.path
-        from common import run_forced_host_child
+        from common import run_sharded_mode
     from repro.obs import load_records
-    run_forced_host_child(__file__, quick, devices)
+    run_sharded_mode(__file__, _sharded_child, quick, devices)
     rec = load_records(SHARDED_JSON)[0]
     m = rec["metrics"]
     return [
@@ -340,6 +343,8 @@ def main():
     ap.add_argument("--kind", default="sharded",
                     choices=["sharded", "scale"], help=argparse.SUPPRESS)
     args = ap.parse_args()
+    from repro.launch import runtime
+    runtime.init()
     if args.child:
         (_scale_child if args.kind == "scale" else _sharded_child)(args.quick)
         return

@@ -294,6 +294,8 @@ def main():
                     help="sweep tile sizes and update the checked-in table "
                          "instead of emitting the bench record")
     args = ap.parse_args()
+    from repro.launch import runtime
+    runtime.init()
     if args.autotune:
         rows = run_autotune(args.quick)
     else:

@@ -25,6 +25,8 @@ def main() -> None:
     ap.add_argument("--only", default=None,
                     help="comma-separated benchmark names")
     args = ap.parse_args()
+    from repro.launch import runtime
+    runtime.init()
 
     suites = args.only.split(",") if args.only else SUITES
     print("name,us_per_call,derived")

@@ -29,6 +29,8 @@ from repro.core import build_knn_graph, engine, two_means_tree
 from repro.core.distributed import ShardedEngine
 from repro.core.two_means import pad_plan
 from repro.data import gmm_blobs
+from repro.launch import runtime
+from repro.launch.mesh import data_mesh
 from repro.obs import emit, sync_counter
 from repro.obs import telemetry as obs_tel
 
@@ -42,6 +44,7 @@ def main():
     ap.add_argument("--emit", default=None, metavar="PATH",
                     help="write the run record to PATH instead of stdout")
     args = ap.parse_args()
+    runtime.init()
 
     key = jax.random.PRNGKey(0)
     print(f"[data] generating n={args.n} d={args.d}")
@@ -53,13 +56,11 @@ def main():
         raise SystemExit(f"k={args.k} must be a power of two")
     if args.n < args.k:
         raise SystemExit(f"n={args.n} must be at least k={args.k}")
-    # ShardedEngine needs equal per-shard cluster blocks (k % R == 0);
-    # an incompatible mesh falls back to the single-device engine — the
-    # same loop, same one-sync contract, just not SPMD
-    sharded = n_dev > 1 and args.k % n_dev == 0
-    if n_dev > 1 and not sharded:
-        print(f"[mesh] k={args.k} not divisible by {n_dev} devices — "
-              f"running the single-device engine")
+    # ShardedEngine needs equal per-shard cluster blocks (k % R == 0)
+    if args.k % n_dev:
+        raise SystemExit(f"k={args.k} does not divide over the {n_dev} "
+                         f"devices: ShardedEngine needs equal cluster blocks")
+    sharded = n_dev > 1
 
     t0 = time.time()
     g, gdiag = build_knn_graph(X, 16, xi=64, tau=4, key=key,
@@ -84,8 +85,7 @@ def main():
                               min_move_frac=1e-4, telemetry=True)
     t0 = time.time()
     if sharded:
-        mesh = jax.make_mesh((n_dev,), ("data",))
-        eng = ShardedEngine(mesh, cfg)
+        eng = ShardedEngine(data_mesh(n_dev), cfg)
         G = jnp.maximum(g.ids, 0)
         with sync_counter() as sc:
             out = eng.run(X, G, st.assign, st.D, st.cnt, key)

@@ -18,7 +18,9 @@ import jax.numpy as jnp
 from repro import index as ivf
 from repro.core import build_knn_graph, gk_means, graph_search
 from repro.data import gmm_blobs
+from repro.launch import runtime
 
+runtime.init()
 key = jax.random.PRNGKey(0)
 n, d = 32768, 64
 X = gmm_blobs(key, n, d, 512)

@@ -8,12 +8,14 @@ import jax
 
 from repro.core import brute_force_knn, gk_means, lloyd, recall_top1
 from repro.data import gmm_blobs
+from repro.launch import runtime
 
 ap = argparse.ArgumentParser()
 ap.add_argument("--n", type=int, default=16384)
 ap.add_argument("--k", type=int, default=256)
 ap.add_argument("--d", type=int, default=64)
 args = ap.parse_args()
+runtime.init()
 
 key = jax.random.PRNGKey(0)
 X = gmm_blobs(key, args.n, args.d, args.k)

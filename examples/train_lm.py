@@ -8,8 +8,9 @@ dry-run — only the preset differs.
 """
 import argparse
 
-from repro.launch.train import scaled_config, train
+from repro.launch import runtime
 from repro.launch.llm_cost import param_counts
+from repro.launch.train import scaled_config, train
 
 
 def main():
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--seq", type=int, default=512)
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_lm")
     args = ap.parse_args()
+    runtime.init()
 
     cfg = scaled_config(args.arch, "m100")
     tot, act = param_counts(cfg)

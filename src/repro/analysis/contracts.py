@@ -175,7 +175,7 @@ def contract_engine_run() -> List[AuditResult]:
     out = []
     for tel in (False, True):
         cfg = engine.EngineConfig(batch_size=96, iters=ITERS, telemetry=tel)
-        low = engine._run_plain.lower(X, state, src, key, cfg)
+        low = engine.run.lower(X, state, src, key, cfg)
         out.append(audit_trace(
             f"engine.run[telemetry={'on' if tel else 'off'}]", low,
             collectives={},
@@ -197,7 +197,8 @@ def contract_engine_sharded() -> List[AuditResult]:
     X, G, assign = _data(key, N, D, K)
     D0 = jnp.zeros((K, D), jnp.float32)
     cnt = jnp.zeros((K,), jnp.float32)
-    mesh = jax.make_mesh((DEVICES,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(DEVICES)
     nb = N // DEVICES // 96          # per-shard batches per epoch
     roles = {N: "n", K: "k"}
     out = []
@@ -247,7 +248,8 @@ def contract_graph_build() -> List[AuditResult]:
     X, _, _ = _data(key, N, D, K)
     cfg = GraphBuildConfig(kappa=KAPPA, tau=TAU, chunk=96)
     k0, n_pad = _plan(N, cfg)
-    mesh = jax.make_mesh((DEVICES,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(DEVICES)
     gb = sharded_graph_builder(mesh, cfg)
     low = gb._make_program(N).lower(X, key)
     roles = {N: "n", K: "k"}
@@ -290,7 +292,8 @@ def contract_ivf_search() -> List[AuditResult]:
     C = gmm_blobs(jax.random.fold_in(key, 1), K, D, 8)
     a, _ = ref.assign_centroids(X, C)
     index = ivf.build_ivf(X, _Result(a, C, K), block_rows=16)
-    mesh = jax.make_mesh((DEVICES,), ("data",))
+    from repro.launch.mesh import data_mesh
+    mesh = data_mesh(DEVICES)
     sivf = ShardedIvf(mesh, index)
     Qr = X[:Q]
     p = sivf.parts

@@ -63,7 +63,7 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.core.engine import (CandidateSource, EngineConfig, dense_source,
                                graph_source, probe_source,
@@ -161,18 +161,18 @@ class ShardedEngine:
         self._epoch = jax.jit(shard_map(
             epoch_fn, mesh=mesh,
             in_specs=(row, row, row, row, rep, rep, row, row, rep),
-            out_specs=(row, row, rep, rep), check_rep=False))
+            out_specs=(row, row, rep, rep), check_vma=False))
         # trailing rep spec covers `tel` — P() over the disabled path's None
         # (an empty pytree) is a no-op, so one spec list serves both modes
         self._run = jax.jit(shard_map(
             run_fn, mesh=mesh,
             in_specs=(row, row, row, row, rep, rep, row, row, rep),
             out_specs=(row, row, rep, rep, rep, rep, rep, rep),
-            check_rep=False))
+            check_vma=False))
         self._distortion = jax.jit(shard_map(
             dist_fn, mesh=mesh,
             in_specs=(row, row, row, rep, row, row, rep),
-            out_specs=rep, check_rep=False))
+            out_specs=rep, check_vma=False))
 
     def _pad(self, k: int, X, *rows):
         """Zero-pad row-sharded arrays to n_pad = ceil(n/R)*R; returns the
@@ -465,12 +465,12 @@ class ShardedIvf:
             prog = jax.jit(shard_map(
                 body_codec, mesh=self.mesh,
                 in_specs=(rep, row, row, row, row, row, row, row, row, rep),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
         else:
             prog = jax.jit(shard_map(
                 body, mesh=self.mesh,
                 in_specs=(rep, row, row, row, row, row, row),
-                out_specs=out_specs, check_rep=False))
+                out_specs=out_specs, check_vma=False))
         self._progs[key] = prog
         return prog
 

@@ -19,7 +19,7 @@ also *emulate* an R-way sharded visit order bit-exactly (``cfg.shards``),
 which is how the parity tests pin the two topologies together.
 
 ``run`` is the fully device-resident multi-epoch driver: a
-``jax.lax.while_loop`` over donated ``BKMState`` with the ``min_move_frac``
+``jax.lax.while_loop`` over ``BKMState`` with the ``min_move_frac``
 early stop *inside* the trace and per-epoch distortion computed in O(k·d)
 from the running statistics (``sum||x||² − Σ_c ||D_c||²/n_c``, with the
 ``sum||x||²`` term hoisted out of the loop) — one host sync per run instead
@@ -756,15 +756,12 @@ def _run_impl(X, state, source, key, cfg, valid=None):
     return st, hist, mhist, t, final, tel
 
 
-_run_donate = jax.jit(_run_impl, static_argnums=(4,), donate_argnums=(1,))
-_run_plain = jax.jit(_run_impl, static_argnums=(4,))
-
-
+@functools.partial(jax.jit, static_argnums=(4,))
 def run(X: jax.Array, state: BKMState, source: CandidateSource,
         key: jax.Array, cfg: EngineConfig, valid=None
         ) -> Tuple[BKMState, jax.Array, jax.Array, jax.Array, jax.Array,
                    Optional[obs_tel.Telemetry]]:
-    """Device-resident multi-epoch run (state buffers donated on accelerators).
+    """Device-resident multi-epoch run.
 
     Returns (state, hist (iters,) f32 per-epoch distortion (NaN past the
     early stop), mhist (iters,) int32 per-epoch accepted moves, epochs ()
@@ -775,23 +772,10 @@ def run(X: jax.Array, state: BKMState, source: CandidateSource,
     while_loop it returns in the SAME host sync as the state.  The whole
     loop — including the ``min_move_frac`` early stop and the per-epoch
     distortion — runs inside one trace: callers pay one host sync per run,
-    not one per epoch.
+    not one per epoch.  The state is not donated: it is O(n + k·d), and the
+    caller keeps its input state.
     """
-    f = _run_plain if jax.default_backend() == "cpu" else _run_donate
-    return f(X, state, source, key, cfg, valid)
-
-
-def run_inline(X: jax.Array, state: BKMState, source: CandidateSource,
-               key: jax.Array, cfg: EngineConfig, valid=None
-               ) -> Tuple[BKMState, jax.Array, jax.Array, jax.Array,
-                          jax.Array, Optional[obs_tel.Telemetry]]:
-    """``run`` without buffer donation — safe under vmap / an outer trace.
-
-    Same return signature as ``run``; use this when the multi-epoch loop is
-    itself mapped (e.g. ``kv_cluster`` vmaps a run per cache slice), where
-    the donated-state variant would be inlined and its donation dropped.
-    """
-    return _run_plain(X, state, source, key, cfg, valid)
+    return _run_impl(X, state, source, key, cfg, valid)
 
 
 # ---------------------------------------------------------------------------

@@ -41,6 +41,8 @@ class GKMeansResult:
     # per-epoch engine Telemetry (None unless gk_means(telemetry=True));
     # rows past the early stop are zero — truncate with `epochs` like history
     telemetry: Optional["object"] = None
+    # distortion of the 2M-tree initialisation, before any engine epoch
+    distortion_init: float = float("nan")
 
 
 def _tree_init(X: jax.Array, k: int, key: jax.Array) -> jax.Array:
@@ -90,16 +92,17 @@ def gk_means(
                                            guided=guided_graph,
                                            return_diagnostics=True)
 
-    # init + engine run are dispatched back-to-back with no host sync in
-    # between (neither span sets .result, so neither blocks); "init"
-    # therefore measures dispatch only and the sync cost lands in "iter"
-    # (the single device_get below).
+    # graph build, init and engine run are dispatched back-to-back with no
+    # host sync in between (no span sets .result, so none blocks): "graph"
+    # and "init" measure compile + dispatch, and the device time of all
+    # three lands in "iter" (the single device_get below).
     with span("init", out=sec):
-        assign = _tree_init(X, k2, ki)
+        state = engine.init_state(X, _tree_init(X, k2, ki), k2)
+        dist0_d = engine.stats_distortion(
+            jnp.sum(jnp.square(X.astype(jnp.float32))), state.D, state.cnt, n)
 
     with span("iter", out=sec):
         source = engine.graph_source(graph.ids)
-        state = engine.init_state(X, assign, k2)
         cfg = engine.EngineConfig(batch_size=min(batch_size, n), mode=mode,
                                   iters=iters, min_move_frac=min_move_frac,
                                   telemetry=telemetry)
@@ -110,11 +113,11 @@ def gk_means(
         # the run's ONE host sync: everything below is numpy (the telemetry
         # rides the same sync — it was accumulated inside the run's
         # while_loop)
-        state, hist, moves, epochs, final, C, tel = jax.device_get(
-            (state, hist_d, moves_d, epochs_d, final_d, C, tel_d))
+        state, hist, moves, epochs, final, C, tel, dist0 = jax.device_get(
+            (state, hist_d, moves_d, epochs_d, final_d, C, tel_d, dist0_d))
 
     epochs = int(epochs)
     history = [float(h) for h in hist[:epochs]]
     return GKMeansResult(state.assign, C, k2, float(final), history,
                          [int(m) for m in moves[:epochs]], graph, sec,
-                         gdiag, tel)
+                         gdiag, tel, float(dist0))

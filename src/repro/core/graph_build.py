@@ -464,7 +464,7 @@ class GraphBuilder:
                 f"k0={k0} must divide the {self.shards}-way mesh for the "
                 "cluster-sharded guided pass (raise xi or shrink the mesh)")
         from jax.sharding import PartitionSpec as P
-        from jax.experimental.shard_map import shard_map
+        from jax import shard_map
         row, rep = P(self.data_axes), P()
         comm = engine._Comm(self.data_axes)
 
@@ -477,7 +477,7 @@ class GraphBuilder:
         sharded = shard_map(body, mesh=self.mesh,
                             in_specs=(row, row, rep, rep),
                             out_specs=(row, row, rep, rep, rep),
-                            check_rep=False)
+                            check_vma=False)
 
         def program(X, key):
             kpad, kb = jax.random.split(key)

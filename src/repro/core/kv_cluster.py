@@ -70,15 +70,15 @@ def build_kv_clusters(keys: jax.Array, kc: int, key: jax.Array,
                       )(flat.astype(jnp.float32), keys_r)        # (BH, S)
 
     if refine_epochs:
-        # the engine's device-resident run (vmapped over cache slices, so
-        # the non-donating entry point): same per-epoch fold_in schedule as
-        # a host loop of epochs, whole loop in one trace
+        # the engine's device-resident run, vmapped over cache slices: same
+        # per-epoch fold_in schedule as a host loop of epochs, whole loop in
+        # one trace
         cfg = engine.EngineConfig(batch_size=min(1024, S), mode=refine_mode,
                                   iters=refine_epochs, min_move_frac=-1.0)
         source = engine.dense_source()
 
         def refine(x, a, kk):
-            st, _, _, _, _, _ = engine.run_inline(
+            st, _, _, _, _, _ = engine.run(
                 x, engine.init_state(x, a, kc), source, kk, cfg)
             return st.assign
 

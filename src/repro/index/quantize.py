@@ -11,7 +11,7 @@ fused `ivf_scan_adc` kernel can score without decoding:
   ``d``, the same alignment the f32 scan's bitwise kernel/ref parity
   already relies on.
 - ``pq``: product quantization — d splits into ``nsub`` subspaces, each with
-  a 256-entry codebook trained by `engine.run_inline` (the paper's own
+  a 256-entry codebook trained by `engine.run` (the paper's own
   "k-means builds the index for k-means" trick, mode='lloyd').  Codes are
   (n_rows, nsub) u8; the per-query LUT holds ``-2 q_m . codebook[m, v]``.
 
@@ -39,6 +39,8 @@ import numpy as np
 from repro.kernels import ref as kref
 
 PQ_VOCAB = 256          # codebook entries per subspace (one u8 code)
+ENCODE_ROWS = 65536     # pq encode chunk: bounds the (rows, nsub, 256) f32
+#                         distance block (8 GiB unchunked at 1M x 8 subs)
 
 
 @jax.tree_util.register_pytree_node_class
@@ -97,7 +99,7 @@ def train_pq(X: jax.Array, nsub: int, *, key: jax.Array | None = None,
              iters: int = 8, batch_size: int = 1024) -> PqCodec:
     """Train one 256-entry codebook per subspace with the engine's k-means.
 
-    Each subspace reuses `engine.run_inline` (mode='lloyd') as the
+    Each subspace reuses `engine.run` (mode='lloyd') as the
     sub-k-means, seeded from a random draw of distinct training rows.  When
     fewer than 256 training rows exist the codebook is padded by repeating
     row 0 — exact duplicates, so `encode`'s stable argmin can never emit a
@@ -123,8 +125,8 @@ def train_pq(X: jax.Array, nsub: int, *, key: jax.Array | None = None,
         seeds = Xm[epoch_order(km, n)[:ksub]]
         assign0, _ = kref.assign_centroids(Xm, seeds)
         state = engine.init_state(Xm, assign0, ksub)
-        state, *_ = engine.run_inline(Xm, state, engine.dense_source(),
-                                      jax.random.fold_in(km, 1), cfg)
+        state, *_ = engine.run(Xm, state, engine.dense_source(),
+                               jax.random.fold_in(km, 1), cfg)
         book = state.D / jnp.maximum(state.cnt, 1)[:, None].astype(jnp.float32)
         if ksub < PQ_VOCAB:
             book = jnp.concatenate(
@@ -153,11 +155,23 @@ def encode(codec: Codec, X: jax.Array) -> jax.Array:
     if codec.kind == "int8":
         c = jnp.round((X - codec.zero[None, :]) / codec.scale[None, :])
         return jnp.clip(c, 0.0, 255.0).astype(jnp.uint8)
-    nsub, dsub = codec.nsub, codec.dsub
+    n = X.shape[0]
+    if n <= ENCODE_ROWS:
+        return _encode_pq(codec.codebook, X)
+    pad = -n % ENCODE_ROWS
+    Xc = jnp.pad(X, ((0, pad), (0, 0))).reshape(-1, ENCODE_ROWS, X.shape[1])
+    codes = jax.lax.map(lambda xc: _encode_pq(codec.codebook, xc), Xc)
+    return codes.reshape(-1, codec.nsub)[:n]
+
+
+def _encode_pq(codebook: jax.Array, X: jax.Array) -> jax.Array:
+    """Nearest codebook entry per subspace; rows are independent, so the
+    chunking in ``encode`` never changes a code."""
+    nsub, _, dsub = codebook.shape
     Xs = X.reshape(X.shape[0], nsub, dsub)
     # ||x_m - book_m||^2 up to the x^2 term, argmin ties -> lowest code
-    d2 = (jnp.sum(codec.codebook ** 2, axis=-1)[None]
-          - 2.0 * jnp.einsum("nmd,mvd->nmv", Xs, codec.codebook))
+    d2 = (jnp.sum(codebook ** 2, axis=-1)[None]
+          - 2.0 * jnp.einsum("nmd,mvd->nmv", Xs, codebook))
     return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
 
 

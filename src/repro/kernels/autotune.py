@@ -12,7 +12,11 @@ Tile semantics are identical on every backend because the tiled arithmetic
 is bitwise tile-invariant (see ``ref.batched_gather_dots``): on TPU the tile
 is the Pallas kernel's ``bB`` row-tile (VMEM working-set size), on CPU it is
 the ``lax.map`` chunk of the reference's gathered working set (cache
-blocking).  ``tile=0`` means "one tile for the whole batch".
+blocking).  ``tile=0`` means "one tile for the whole batch" — capped, in
+the TPU kernels, by their VMEM budget (``row_tile``).  Only rows of the
+running backend are ever read: the checked-in rows are all ``cpu``, so on
+a TPU every kernel runs at its VMEM-budget tile until a chip sweep records
+``tpu`` rows.
 
 Table schema (``repro.autotune.v1``)::
 

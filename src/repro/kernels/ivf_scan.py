@@ -30,33 +30,46 @@ from repro.kernels import ref as _ref
 from repro.kernels.centroid_assign import _select_topk
 
 
-def _kernel(tile_map_ref, q_ref, v_ref, id_ref, oid_ref, od_ref, *,
-            topk: int):
-    t = pl.program_id(1)
-    q = q_ref[...].astype(jnp.float32)          # (1, d)
+def tile_scores(q, v_ref, id_ref):
+    """Partial distances ``||v||^2 - 2 q.v`` of queries q (g, d) against one
+    packed tile, +inf at padding rows -> (part (g, bl), ids (1, bl))."""
     v = v_ref[...].astype(jnp.float32)          # (bl, d)
-    ids = id_ref[...]                           # (bl,) int32, -1 = padding
-
+    ids = id_ref[...]                           # (1, bl) int32, -1 = padding
     dots = jax.lax.dot_general(
         q, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)     # (1, bl)
+        precision=jax.lax.Precision.HIGHEST,
+        preferred_element_type=jnp.float32)     # (g, bl)
     vsq = jnp.sum(v * v, axis=-1)               # (bl,)
-    part = vsq[None, :] - 2.0 * dots            # (1, bl): d2 minus ||q||^2
-    part = jnp.where(ids[None, :] < 0, jnp.inf, part)
+    part = vsq[None, :] - 2.0 * dots            # d2 minus ||q||^2
+    return jnp.where(ids < 0, jnp.inf, part), ids
 
-    @pl.when(t == 0)
+
+def running_topk(od_ref, oid_ref, rows, first, part, ids, topk: int):
+    """Fold one tile's (part, ids) into the running top-k held in
+    ``od_ref[rows]`` / ``oid_ref[rows]`` (overwritten on the first tile)."""
+    @pl.when(first)
     def _init():
-        d0, i0 = _select_topk(part, ids[None, :], topk)
-        od_ref[...] = d0
-        oid_ref[...] = i0
+        d0, i0 = _select_topk(part, ids, topk)
+        od_ref[rows, :] = d0
+        oid_ref[rows, :] = i0
 
-    @pl.when(t > 0)
+    @pl.when(jnp.logical_not(first))
     def _update():
-        d = jnp.concatenate([od_ref[...], part], axis=-1)
-        i = jnp.concatenate([oid_ref[...], ids[None, :]], axis=-1)
+        d = jnp.concatenate([od_ref[rows, :], part], axis=-1)
+        i = jnp.concatenate([oid_ref[rows, :], ids], axis=-1)
         d1, i1 = _select_topk(d, i, topk)
-        od_ref[...] = d1
-        oid_ref[...] = i1
+        od_ref[rows, :] = d1
+        oid_ref[rows, :] = i1
+
+
+def _kernel(tile_map_ref, q_ref, v_ref, id_ref, oid_ref, od_ref, *,
+            topk: int):
+    i = pl.program_id(0)
+    t = pl.program_id(1)
+    row = pl.ds(i, 1)
+    q = q_ref[row, :].astype(jnp.float32)       # (1, d)
+    part, ids = tile_scores(q, v_ref, id_ref)   # (1, bl)
+    running_topk(od_ref, oid_ref, row, t == 0, part, ids, topk)
 
 
 @functools.partial(jax.jit,
@@ -83,18 +96,16 @@ def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
     assert tile_map.shape[0] == nq
     T = tile_map.shape[1]
 
+    whole = lambda shape: pl.BlockSpec(shape, lambda i, t, tm: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nq, T),
         in_specs=[
-            pl.BlockSpec((1, d), lambda i, t, tm: (i, 0)),
+            whole((nq, d)),
             pl.BlockSpec((block_rows, d), lambda i, t, tm: (tm[i, t], 0)),
-            pl.BlockSpec((block_rows,), lambda i, t, tm: (tm[i, t],)),
+            pl.BlockSpec((1, block_rows), lambda i, t, tm: (0, tm[i, t])),
         ],
-        out_specs=[
-            pl.BlockSpec((1, topk), lambda i, t, tm: (i, 0)),
-            pl.BlockSpec((1, topk), lambda i, t, tm: (i, 0)),
-        ],
+        out_specs=[whole((nq, topk)), whole((nq, topk))],
     )
     oid, od = pl.pallas_call(
         functools.partial(_kernel, topk=topk),
@@ -104,7 +115,7 @@ def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
             jax.ShapeDtypeStruct((nq, topk), jnp.float32),
         ],
         interpret=interpret,
-    )(tile_map.astype(jnp.int32), Q, vecs, pids.astype(jnp.int32))
+    )(tile_map.astype(jnp.int32), Q, vecs, pids.astype(jnp.int32)[None, :])
     if raw:
         return oid, jnp.where(oid < 0, jnp.inf, od)
     return _ref.finalize_d2(oid, od, Q)
@@ -114,33 +125,17 @@ def _grouped_kernel(union_ref, qg_ref, v_ref, id_ref, m_ref, oid_ref, od_ref,
                     *, topk: int):
     s = pl.program_id(1)
     qg = qg_ref[...].astype(jnp.float32)        # (G, d)
-    v = v_ref[...].astype(jnp.float32)          # (bl, d)
-    ids = id_ref[...]                           # (bl,) int32, -1 = padding
-    probed = m_ref[...]                         # (G, 1) int32 membership
-
-    dots = jax.lax.dot_general(
-        qg, v, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32)     # (G, bl)
-    vsq = jnp.sum(v * v, axis=-1)               # (bl,)
-    part = vsq[None, :] - 2.0 * dots            # (G, bl): d2 minus ||q||^2
+    part, ids = tile_scores(qg, v_ref, id_ref)  # (G, bl), (1, bl)
+    # membership of union slot s: the group's (G, U) mask block reduced at
+    # lane s (a (G, 1) block breaks the TPU's (8, 128) block tiling)
+    m = m_ref[...]
+    lane = jax.lax.broadcasted_iota(jnp.int32, m.shape, 1)
+    probed = jnp.sum(jnp.where(lane == s, m, 0), axis=-1, keepdims=True)
     # a query only sees this tile's rows if it probed the tile; padding rows
     # and unprobed tiles become id=-1/inf so the select treats them as holes
-    idsb = jnp.where((probed > 0) & (ids[None, :] >= 0), ids[None, :], -1)
+    idsb = jnp.where((probed > 0) & (ids >= 0), ids, -1)
     part = jnp.where(idsb < 0, jnp.inf, part)
-
-    @pl.when(s == 0)
-    def _init():
-        d0, i0 = _select_topk(part, idsb, topk)
-        od_ref[...] = d0
-        oid_ref[...] = i0
-
-    @pl.when(s > 0)
-    def _update():
-        d = jnp.concatenate([od_ref[...], part], axis=-1)
-        i = jnp.concatenate([oid_ref[...], idsb], axis=-1)
-        d1, i1 = _select_topk(d, i, topk)
-        od_ref[...] = d1
-        oid_ref[...] = i1
+    running_topk(od_ref, oid_ref, slice(None), s == 0, part, idsb, topk)
 
 
 @functools.partial(jax.jit,
@@ -180,8 +175,8 @@ def ivf_scan_grouped(Qg: jax.Array, vecs: jax.Array, pids: jax.Array,
         in_specs=[
             pl.BlockSpec((G, d), lambda g, s, ut: (g, 0)),
             pl.BlockSpec((block_rows, d), lambda g, s, ut: (ut[g, s], 0)),
-            pl.BlockSpec((block_rows,), lambda g, s, ut: (ut[g, s],)),
-            pl.BlockSpec((G, 1), lambda g, s, ut: (g, s)),
+            pl.BlockSpec((1, block_rows), lambda g, s, ut: (0, ut[g, s])),
+            pl.BlockSpec((G, U), lambda g, s, ut: (g, 0)),
         ],
         out_specs=[
             pl.BlockSpec((G, topk), lambda g, s, ut: (g, 0)),
@@ -196,8 +191,8 @@ def ivf_scan_grouped(Qg: jax.Array, vecs: jax.Array, pids: jax.Array,
             jax.ShapeDtypeStruct((nqg, topk), jnp.float32),
         ],
         interpret=interpret,
-    )(union_tiles.astype(jnp.int32), Qg, vecs, pids.astype(jnp.int32),
-      qmask.astype(jnp.int32))
+    )(union_tiles.astype(jnp.int32), Qg, vecs,
+      pids.astype(jnp.int32)[None, :], qmask.astype(jnp.int32))
     if raw:
         return oid, jnp.where(oid < 0, jnp.inf, od)
     return _ref.finalize_d2(oid, od, Qg)

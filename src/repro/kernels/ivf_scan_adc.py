@@ -1,12 +1,13 @@
 """Pallas TPU kernel: fused asymmetric-distance scan over compressed lists.
 
-Same scalar-prefetch tile streaming and running top-k as `ivf_scan`, but the
+Same scalar-prefetch tile streaming and running top-k as `ivf_scan` (whole-
+array (q, topk) output blocks, row i rewritten by grid row i), but the
 candidate payload is u8 codes (`index/quantize.py`) instead of f32 rows: the
 per-query distance LUT (q, M, W) is computed ONCE per batch on the host side
-of the trace, its (1, M, W) block stays resident in VMEM for the whole query
-(the index map ignores the tile step), and only codes + reconstruction norms
-stream from HBM — (M + 4) bytes per candidate row instead of 4·d, the whole
-point of the codec.
+of the trace, its flattened (1, 1, M·W) block stays resident in VMEM for the
+whole query (the index map ignores the tile step), and only codes +
+reconstruction norms stream from HBM — (M + 4) bytes per candidate row
+instead of 4·d, the whole point of the codec.
 
 One kernel serves both codecs through the LUT width W (see `ref.adc_expand`):
 W=256 (pq) one-hot-expands each code so the table lookup becomes a single
@@ -31,7 +32,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
-from repro.kernels.centroid_assign import _select_topk
+from repro.kernels.ivf_scan import running_topk
 
 
 def _kernel(tile_map_ref, lut_ref, vn_ref, code_ref, id_ref, opos_ref,
@@ -39,36 +40,22 @@ def _kernel(tile_map_ref, lut_ref, vn_ref, code_ref, id_ref, opos_ref,
     i = pl.program_id(0)
     t = pl.program_id(1)
     lut = lut_ref[...].astype(jnp.float32)      # (1, M, W), VMEM-resident
-    vn = vn_ref[...]                            # (bl,) f32
-    ids = id_ref[...]                           # (bl,) int32, -1 = padding
+    lf = lut.reshape(1, lut.shape[1] * width)
+    vn = vn_ref[...]                            # (1, bl) f32
+    ids = id_ref[...]                           # (1, bl) int32, -1 = padding
 
-    m = lut.shape[1]
-    lf = lut.reshape(1, m * width)
     ex = _ref.adc_expand(code_ref[...], width)  # (bl, M*W)
     cross = jax.lax.dot_general(
         lf, ex, (((1,), (1,)), ((), ())),
         preferred_element_type=jnp.float32)     # (1, bl)
-    part = vn[None, :] + cross                  # ||v̂||² - 2 q.v̂
-    part = jnp.where(ids[None, :] < 0, jnp.inf, part)
+    part = vn + cross                           # ||v̂||² - 2 q.v̂
+    part = jnp.where(ids < 0, jnp.inf, part)
 
     tile = tile_map_ref[i, t]                   # scalar prefetch: SMEM read
     pos = (tile * block_rows
            + jax.lax.broadcasted_iota(jnp.int32, (1, block_rows), 1))
-    pos = jnp.where(ids[None, :] < 0, -1, pos)  # (1, bl) packed positions
-
-    @pl.when(t == 0)
-    def _init():
-        d0, p0 = _select_topk(part, pos, topk)
-        od_ref[...] = d0
-        opos_ref[...] = p0
-
-    @pl.when(t > 0)
-    def _update():
-        d = jnp.concatenate([od_ref[...], part], axis=-1)
-        p = jnp.concatenate([opos_ref[...], pos], axis=-1)
-        d1, p1 = _select_topk(d, p, topk)
-        od_ref[...] = d1
-        opos_ref[...] = p1
+    pos = jnp.where(ids < 0, -1, pos)           # (1, bl) packed positions
+    running_topk(od_ref, opos_ref, pl.ds(i, 1), t == 0, part, pos, topk)
 
 
 @functools.partial(jax.jit,
@@ -98,19 +85,18 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
     assert tile_map.shape[0] == nq
     T = tile_map.shape[1]
 
+    tiled_row = pl.BlockSpec((1, block_rows), lambda i, t, tm: (0, tm[i, t]))
+    whole = pl.BlockSpec((nq, topk), lambda i, t, tm: (0, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
         grid=(nq, T),
         in_specs=[
             pl.BlockSpec((1, m, w), lambda i, t, tm: (i, 0, 0)),
-            pl.BlockSpec((block_rows,), lambda i, t, tm: (tm[i, t],)),
+            tiled_row,
             pl.BlockSpec((block_rows, m), lambda i, t, tm: (tm[i, t], 0)),
-            pl.BlockSpec((block_rows,), lambda i, t, tm: (tm[i, t],)),
+            tiled_row,
         ],
-        out_specs=[
-            pl.BlockSpec((1, topk), lambda i, t, tm: (i, 0)),
-            pl.BlockSpec((1, topk), lambda i, t, tm: (i, 0)),
-        ],
+        out_specs=[whole, whole],
     )
     opos, od = pl.pallas_call(
         functools.partial(_kernel, block_rows=block_rows, topk=topk,
@@ -121,7 +107,8 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
             jax.ShapeDtypeStruct((nq, topk), jnp.float32),
         ],
         interpret=interpret,
-    )(tile_map.astype(jnp.int32), lut.astype(jnp.float32), vnorm, codes,
-      pids.astype(jnp.int32))
+    )(tile_map.astype(jnp.int32),
+      lut.astype(jnp.float32), vnorm[None, :], codes,
+      pids.astype(jnp.int32)[None, :])
     ids = jnp.where(opos < 0, -1, pids.astype(jnp.int32)[jnp.clip(opos, 0)])
     return ids, opos, jnp.where(opos < 0, jnp.inf, od + qconst[:, None])

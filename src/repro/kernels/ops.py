@@ -1,8 +1,9 @@
 """Jit'd public wrappers for the Pallas kernels.
 
-On TPU the Pallas kernels run compiled; on CPU (this container) the hot path
-dispatches to the pure-jnp reference (XLA:CPU), while tests exercise the Pallas
-bodies via ``interpret=True`` to validate them against the same references.
+On TPU the Pallas kernels run compiled; on the CPU the hot path dispatches
+to the pure-jnp references (XLA:CPU).  ``force='interpret' | 'ref' |
+'pallas'`` overrides the dispatch for tests, which run the Pallas bodies in
+interpret mode against the same references.
 
 Every dispatcher runs under ``obs.timing.kernel_scope`` — a
 ``jax.named_scope("repro.kernels.<name>")`` that tags the emitted ops in HLO
@@ -30,8 +31,11 @@ def _on_tpu() -> bool:
 
 def _tile(kernel: str, shape: dict, tile: int | None) -> int:
     """Row-tile for this call: explicit ``tile=`` override, else the
-    checked-in autotune table (see ``kernels.autotune``).  Resolved at trace
-    time — shapes are static under jit, so this is free at runtime."""
+    checked-in autotune table's rows for this backend (see
+    ``kernels.autotune``).  0 is the kernel's own default: the whole batch
+    on the CPU reference, the VMEM-budget tile (``row_tile``) of the TPU
+    kernel.  Resolved at trace time — shapes are static under jit, so this
+    is free at runtime."""
     return _at.resolve(kernel, jax.default_backend(), shape, tile)
 
 

@@ -18,6 +18,16 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+# VMEM the double-buffered blocks of one grid step may take, under the
+# default 16 MiB scoped-VMEM limit of a TPU core
+BLOCK_BYTES = 8 << 20
+
+
+def row_tile(m: int, d_tile: int) -> int:
+    """Clusters per grid step whose double-buffered (bB, m, d_tile) input
+    pair and (bB, m, m) output block fit ``BLOCK_BYTES``."""
+    return max(BLOCK_BYTES // (2 * 4 * (2 * m * d_tile + m * m)), 1)
+
 
 def _kernel(x_ref, xt_ref, out_ref):
     """Grid: (B // bB, d // d_tile). Accumulates -2*X@X^T + norms."""
@@ -51,13 +61,14 @@ def pairwise_sq(Xb: jax.Array, *, d_tile: int = 512, bB: int = 1,
     """Batched squared-L2 distances. Xb: (B, m, d) -> (B, m, m) float32.
 
     ``bB`` clusters are processed per grid step as one batched dot
-    (autotuned via ``kernels.autotune``; 0 = all clusters in one step).
+    (autotuned via ``kernels.autotune``; 0 = all clusters in one step),
+    capped by ``row_tile``'s VMEM budget.
     m should be a multiple of 8 and d a multiple of 128 for TPU lanes; other
     shapes work (Pallas pads) but waste tiles.
     """
     B, m, d = Xb.shape
-    bB = max(1, min(bB if bB else B, B))
     d_tile = min(d_tile, d)
+    bB = max(1, min(bB if bB else B, B, row_tile(m, d_tile)))
     nd = pl.cdiv(d, d_tile)
     return pl.pallas_call(
         _kernel,

@@ -217,9 +217,13 @@ def adc_expand(codes: jax.Array, width: int) -> jax.Array:
     ci = codes.astype(jnp.int32)
     if width == 1:
         return ci.astype(jnp.float32)
-    iota = jax.lax.broadcasted_iota(jnp.int32, ci.shape + (width,), ci.ndim)
-    oh = (ci[..., None] == iota).astype(jnp.float32)
-    return oh.reshape(*ci.shape[:-1], ci.shape[-1] * width)
+    # one lane-aligned (..., width) one-hot block per code column: Mosaic
+    # cannot fold an (M, width) pair of axes into one lane axis by reshape
+    iota = jax.lax.broadcasted_iota(jnp.int32, ci.shape[:-1] + (width,),
+                                    ci.ndim - 1)
+    return jnp.concatenate(
+        [(ci[..., m:m + 1] == iota).astype(jnp.float32)
+         for m in range(ci.shape[-1])], axis=-1)
 
 
 @functools.partial(jax.jit, static_argnames=("block_rows", "topk", "tile"))

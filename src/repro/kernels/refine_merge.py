@@ -5,22 +5,22 @@ row against C candidate rows (its cluster co-members, Alg. 3, or its
 NN-Descent candidate set) and folds the exact distances into the row's sorted
 top-κ list.  The naive formulation materialises a (B, C, d) candidate gather
 and a (B, C) distance matrix in HBM, then runs a three-argsort dedupe merge
-(``knn_graph.merge_topk``) over (B, κ + C).  This kernel streams each
-candidate row straight from HBM into VMEM via scalar-prefetch-driven block
-indexing (the same revisiting pattern as ``gather_score``) — neither the
-gathered tensor nor the distance matrix ever exists in HBM, and the merge
-costs O(κ(κ+C)) lane ops instead of three sorts.
+(``knn_graph.merge_topk``) over (B, κ + C).  This kernel gathers each
+candidate row straight from HBM into VMEM by manual DMA (the same
+``gather_score.gather_rows`` loop over the scalar-prefetched row table) —
+neither the gathered tensor nor the distance matrix ever exists in HBM, and
+the merge costs O(κ(κ+C)) lane ops instead of three sorts.
 
-Grid: (B // bB, bB, C), gather axes innermost.  Each (b, c) step parks one
-gathered candidate row in the tile's VMEM scratch; the tile's LAST step
-computes all bB x C distances at once in MXU form — one (bB, d) x (bB, C, d)
-batched ``dot_general`` (sample axis = batch dim) plus hoisted source norms,
-``max(||y||² + ||x||² − 2·x·y, 0)`` — and runs the vectorised merge
-(``ref.merge_lists``: repeated first-minimum with retire-all-copies of the
-selected id) over the whole (bB, κ+C) tile.  Row tiling is bitwise-invariant
-(batch dims evaluate per-row; the merge is elementwise per row), so every
-``bB`` matches the whole-batch oracle exactly; ragged tails pad the row
-table with entry 0 and slice the results off.
+Grid: (B // bB,).  Each step DMA-gathers the tile's bB x C candidate rows
+into VMEM, computes all bB x C distances at once in MXU form — one
+(bB, d) x (bB, C, d) batched ``dot_general`` (sample axis = batch dim) plus
+hoisted source norms, ``max(||y||² + ||x||² − 2·x·y, 0)`` — and runs the
+vectorised merge (``ref.merge_lists``: repeated first-minimum with
+retire-all-copies of the selected id) over the whole (bB, κ+C) tile.  Row
+tiling is bitwise-invariant (batch dims evaluate per-row; the merge is
+elementwise per row), so every ``bB`` (a multiple of 8) matches the
+whole-batch oracle exactly; ragged tails pad the row table with entry 0 and
+slice the results off.
 """
 from __future__ import annotations
 
@@ -32,33 +32,27 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
+from repro.kernels.gather_score import (_round8, batched_rowdot,
+                                        gather_rows, gathered, lane_rows,
+                                        row_tile)
 
 
-def _kernel(rows_ref, x_ref, y_ref, ysq_ref, oldi_ref, oldd_ref, candi_ref,
-            outi_ref, outd_ref, Y_ref, *, bB: int, C: int, kappa: int,
+def _kernel(rows_ref, x_ref, ysq_ref, oldi_ref, oldd_ref, candi_ref, X_hbm,
+            outi_ref, outd_ref, Y_ref, sem, *, bB: int, C: int, kappa: int,
             d0: int):
-    b = pl.program_id(1)
-    c = pl.program_id(2)
-    # park the gathered candidate row in the tile's (bB*C, d) scratch
-    Y_ref[pl.ds(b * C + c, 1), :] = y_ref[...].astype(jnp.float32)
-
-    @pl.when((b == bB - 1) & (c == C - 1))
-    def _merge():
-        # contract over the NATIVE d0 lanes only — blocks are lane-padded
-        # for the memory layout, but the arithmetic must match ref.py's
-        # unpadded reductions bitwise (see gather_score._kernel)
-        x = x_ref[...].astype(jnp.float32)[:, :d0]      # (bB, d0)
-        Y = Y_ref[...].reshape(bB, C, -1)[:, :, :d0]
-        dots = jax.lax.dot_general(
-            x, Y, (((1,), (2,)), ((0,), (0,))),
-            preferred_element_type=jnp.float32)         # (bB, C)
-        xsq = jnp.sum(x * x, axis=-1)                   # (bB,)
-        cd = jnp.maximum(ysq_ref[...] + xsq[:, None] - 2.0 * dots, 0.0)
-        oi, od = _ref.merge_lists(oldi_ref[...],
-                                  oldd_ref[...].astype(jnp.float32),
-                                  candi_ref[...], cd, kappa)
-        outi_ref[...] = oi
-        outd_ref[...] = od
+    i = pl.program_id(0)
+    gather_rows(rows_ref, X_hbm, Y_ref, sem, i * bB * C, bB, C)
+    # contract over the NATIVE d0 lanes only — blocks are lane-padded for
+    # the memory layout, but the arithmetic must match ref.py's unpadded
+    # reductions bitwise (see gather_score._kernel)
+    x = x_ref[...].astype(jnp.float32)[:, :d0]          # (bB, d0)
+    dots = batched_rowdot(x, gathered(Y_ref, C, d0))    # (bB, C)
+    xsq = jnp.sum(x * x, axis=-1)                       # (bB,)
+    cd = jnp.maximum(ysq_ref[...] + xsq[:, None] - 2.0 * dots, 0.0)
+    oi, od = _ref.merge_lists(oldi_ref[...], oldd_ref[...].astype(jnp.float32),
+                              candi_ref[...], cd, kappa)
+    outi_ref[...] = oi
+    outd_ref[...] = od
 
 
 @functools.partial(jax.jit, static_argnames=("bB", "interpret"))
@@ -70,7 +64,8 @@ def refine_merge(x: jax.Array, rows: jax.Array, cand_ids: jax.Array,
     x: (B, d) row vectors; rows: (B, C) int32 indices into Xsrc (pre-clamped
     >= 0); cand_ids: (B, C) int32 neighbour ids (-1 = invalid); old_ids /
     old_d: (B, κ) current lists (-1/inf padded); Xsrc: (N, d).  ``bB`` is
-    the row-tile size (autotuned via ``kernels.autotune``; 0 = one tile).
+    the row-tile size (0 = the whole batch), rounded up to a multiple of 8
+    and capped by ``row_tile``'s VMEM budget.
 
     Returns (ids (B, κ) int32, d (B, κ) float32) ascending by distance,
     id-deduped, -1/inf padded — bitwise-equal to ``ref.refine_merge`` in
@@ -81,9 +76,7 @@ def refine_merge(x: jax.Array, rows: jax.Array, cand_ids: jax.Array,
     kappa = old_ids.shape[1]
     assert rows.shape == cand_ids.shape == (B, C), (rows.shape, cand_ids.shape)
     assert old_ids.shape == old_d.shape == (B, kappa)
-    # clamp bB >= 2: XLA strength-reduces a batch-1 dot_general to a matvec
-    # whose reduction order differs in the last ulp (same clamp as ref.py)
-    bB = max(2, min(bB if bB else B, B))
+    bB = min(_round8(bB or B), row_tile(B, C, d))
     # the source norms reduce over the NATIVE d (before lane-padding) to
     # match ref.py's unpadded reduction bitwise
     Xn = Xsrc.astype(jnp.float32)
@@ -114,21 +107,16 @@ def refine_merge(x: jax.Array, rows: jax.Array, cand_ids: jax.Array,
     Xf = Xsrc.astype(jnp.float32)
     ysq = ysq_src[rows]                                 # (Bp, C)
 
+    row = lambda w: pl.BlockSpec((bB, w), lambda i, rows: (i, 0))
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(nt, bB, C),
-        in_specs=[
-            pl.BlockSpec((bB, d), lambda i, b, c, rows: (i, 0)),
-            pl.BlockSpec((1, d),
-                         lambda i, b, c, rows: (rows[i * bB + b, c], 0)),
-            pl.BlockSpec((bB, C), lambda i, b, c, rows: (i, 0)),
-            pl.BlockSpec((bB, kappa), lambda i, b, c, rows: (i, 0)),
-            pl.BlockSpec((bB, kappa), lambda i, b, c, rows: (i, 0)),
-            pl.BlockSpec((bB, C), lambda i, b, c, rows: (i, 0)),
-        ],
-        out_specs=(pl.BlockSpec((bB, kappa), lambda i, b, c, rows: (i, 0)),
-                   pl.BlockSpec((bB, kappa), lambda i, b, c, rows: (i, 0))),
-        scratch_shapes=[pltpu.VMEM((bB * C, d), jnp.float32)],
+        grid=(nt,),
+        in_specs=[row(d), row(C), row(kappa), row(kappa), row(C),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=(row(kappa), row(kappa)),
+        scratch_shapes=[pltpu.VMEM((d // 128, bB, _round8(C), 128),
+                                   jnp.float32),
+                        pltpu.SemaphoreType.DMA(())],
     )
     oi, od = pl.pallas_call(
         functools.partial(_kernel, bB=bB, C=C, kappa=kappa, d0=d0),
@@ -136,5 +124,5 @@ def refine_merge(x: jax.Array, rows: jax.Array, cand_ids: jax.Array,
         out_shape=(jax.ShapeDtypeStruct((Bp, kappa), jnp.int32),
                    jax.ShapeDtypeStruct((Bp, kappa), jnp.float32)),
         interpret=interpret,
-    )(rows, x, Xf, ysq, old_ids, old_d, cand_ids)
+    )(rows.reshape(-1), x, ysq, old_ids, old_d, cand_ids, lane_rows(Xf))
     return oi[:B], od[:B]

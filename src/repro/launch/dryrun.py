@@ -21,7 +21,7 @@ import jax  # noqa: E402
 from repro.configs import SHAPES, get_config, list_archs  # noqa: E402
 from repro.launch import llm_cost as lc  # noqa: E402
 from repro.launch import roofline as rl  # noqa: E402
-from repro.launch.mesh import make_production_mesh  # noqa: E402
+from repro.launch.mesh import PRODUCTION_KIND, make_production_mesh  # noqa: E402
 from repro.launch.specs import input_specs  # noqa: E402
 from repro.train import make_decode_step, make_prefill, make_train_step  # noqa: E402
 
@@ -94,7 +94,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         hb = lc.hbm_analytic(cfg, shape, chips)
         rec["flops_analytic"] = fl
         rec["hbm_bytes_analytic"] = hb
-        terms = rl.roofline_terms(fl, hb, coll["total_wire_bytes"])
+        terms = rl.roofline_terms(fl, hb, coll["total_wire_bytes"],
+                                  device_kind=PRODUCTION_KIND)
         mf = lc.model_flops(cfg, shape)
         terms["model_flops_total"] = mf
         terms["model_flops_per_chip"] = mf / chips
@@ -102,7 +103,8 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
         rec["roofline"] = terms
         terms_raw = rl.roofline_terms(rec["flops_hlo_raw"],
                                       rec["hbm_bytes_hlo_raw"],
-                                      coll_raw["total_wire_bytes"])
+                                      coll_raw["total_wire_bytes"],
+                                      device_kind=PRODUCTION_KIND)
         rec["roofline_hlo_raw"] = terms_raw
     except Exception as e:  # noqa: BLE001 — a failed cell is a recorded bug
         rec["status"] = "error"

@@ -23,7 +23,8 @@ from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
 from repro.core.distributed import ShardedEngine  # noqa: E402
 from repro.core.engine import EngineConfig  # noqa: E402
 from repro.launch import roofline as rl  # noqa: E402
-from repro.launch.mesh import data_axes_of, make_production_mesh  # noqa: E402
+from repro.launch.mesh import (PRODUCTION_KIND, data_axes_of,  # noqa: E402
+                               make_production_mesh)
 
 WORKLOADS = {
     # n is padded to a 512-device multiple; k, kappa, xi follow the paper
@@ -90,7 +91,8 @@ def run_cell(workload: str, mode: str, multi_pod: bool,
             "peak_bytes": rl.peak_memory_bytes(mem),
         }
         rec["roofline"] = rl.roofline_terms(fl, hb,
-                                            coll["total_wire_bytes"])
+                                            coll["total_wire_bytes"],
+                                            device_kind=PRODUCTION_KIND)
     except Exception as e:  # noqa: BLE001
         rec["status"] = "error"
         rec["error"] = f"{type(e).__name__}: {e}"

@@ -33,7 +33,7 @@ import argparse
 import sys
 from typing import Any, Dict, List
 
-from repro.launch.roofline import KERNEL_INVENTORY, roofline_terms
+from repro.launch.roofline import KERNEL_INVENTORY, PEAKS, roofline_terms
 from repro.obs import emit
 
 
@@ -51,10 +51,15 @@ def _fmt_table(header: List[str], rows: List[List[str]]) -> str:
 
 
 def kernel_table(rec: Dict[str, Any]) -> str:
-    """Measured-vs-analytic roofline table from a ``kernels`` record."""
+    """Measured-vs-analytic roofline table from a ``kernels`` record,
+    against the peaks of the device the record was measured on."""
     entries = rec["metrics"].get("kernels", [])
     if not entries:
         raise ReportError("kernels record has no metrics['kernels'] entries")
+    kind = rec["env"].get("device_kind")
+    if kind not in PEAKS:
+        raise ReportError(f"kernels record measured on {kind!r}: no "
+                          f"published peaks (known: {sorted(PEAKS)})")
     rows = []
     for e in entries:
         name = e["kernel"]
@@ -65,7 +70,7 @@ def kernel_table(rec: Dict[str, Any]) -> str:
         shape = e["shape"]
         flops = inv["flops"](*shape.values())
         hbm = inv["hbm_bytes"](*shape.values())
-        terms = roofline_terms(flops, hbm, 0.0)
+        terms = roofline_terms(flops, hbm, 0.0, device_kind=kind)
         bound_us = max(terms["compute_s"], terms["memory_s"]) * 1e6
         meas_us = float(e["us"])
         frac = bound_us / meas_us if meas_us > 0 else 0.0

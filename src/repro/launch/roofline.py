@@ -18,12 +18,30 @@ models, not the clustering kernels, and nothing here depends on them.
 from __future__ import annotations
 
 import re
-from typing import Dict
+from typing import Dict, NamedTuple
 
-# TPU v5e-class hardware constants (assignment-specified)
-PEAK_FLOPS = 197e12      # bf16 FLOP/s per chip
-HBM_BW = 819e9           # bytes/s per chip
-ICI_BW = 50e9            # bytes/s per link
+
+class Peaks(NamedTuple):
+    flops: float         # bf16 FLOP/s per chip
+    hbm_bw: float        # HBM bytes/s per chip
+    ici_bw: float        # chip-to-chip bytes/s per link
+
+
+# Published per-chip peaks keyed by ``jax.Device.device_kind``.  TPU v5e:
+# Google Cloud documentation, "TPU v5e" — 197 TFLOP/s bf16, 819 GB/s HBM,
+# 1,600 Gbit/s of interchip interconnect over 4 links (50 GB/s each).
+# JAX reports a v5e chip as "TPU v5 lite".
+PEAKS = {"TPU v5 lite": Peaks(flops=197e12, hbm_bw=819e9, ici_bw=50e9)}
+
+
+def peaks(device_kind: str) -> Peaks:
+    """Published peaks of one chip; a kind not in ``PEAKS`` is an error,
+    never a default."""
+    try:
+        return PEAKS[device_kind]
+    except KeyError:
+        raise KeyError(f"no published peaks for device kind {device_kind!r} "
+                       f"(known: {sorted(PEAKS)})") from None
 
 # ---------------------------------------------------------------------------
 # Pallas kernel inventory — analytic per-call FLOP / HBM-byte models for the
@@ -380,12 +398,14 @@ def collective_bytes(hlo_text: str) -> Dict[str, Dict[str, float]]:
     return out
 
 
-def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float,
-                   links: int = 3) -> Dict[str, float]:
-    """All three terms in seconds (per chip). `links`: ICI links engaged."""
-    t_c = flops / PEAK_FLOPS
-    t_m = hbm_bytes / HBM_BW
-    t_x = coll_bytes / (ICI_BW * links)
+def roofline_terms(flops: float, hbm_bytes: float, coll_bytes: float, *,
+                   device_kind: str, links: int = 3) -> Dict[str, float]:
+    """All three terms in seconds (per chip of ``device_kind``).
+    `links`: ICI links engaged."""
+    pk = peaks(device_kind)
+    t_c = flops / pk.flops
+    t_m = hbm_bytes / pk.hbm_bw
+    t_x = coll_bytes / (pk.ici_bw * links)
     dom = max(("compute", t_c), ("memory", t_m), ("collective", t_x),
               key=lambda kv: kv[1])
     total = max(t_c, t_m, t_x)

@@ -34,6 +34,7 @@ import numpy as np
 from repro import index as ivf
 from repro.core import gk_means
 from repro.data import gmm_blobs
+from repro.launch import runtime
 
 
 def build(args) -> tuple[ivf.IvfIndex, jax.Array]:
@@ -146,6 +147,7 @@ def main():
     ap.add_argument("--nsub", type=int, default=8,
                     help="pq subspaces (code bytes per vector)")
     args = ap.parse_args()
+    runtime.init()
     if args.codec != "f32" and args.qgroup:
         raise SystemExit("--codec is per-query only (drop --qgroup)")
 

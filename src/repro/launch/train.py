@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from repro.configs import get_config
 from repro.data import token_batch
 from repro.launch import sharding as shd
-from repro.launch.mesh import make_host_mesh
+from repro.launch.mesh import data_mesh
 from repro.models.model import init_params
 from repro.train import make_train_step
 from repro.train import checkpoint as ckpt
@@ -78,7 +78,7 @@ def train(cfg, *, steps: int, batch: int, seq: int, seed: int = 0,
           ckpt_dir: str | None = None, ckpt_every: int = 50,
           resume: bool = False, simulate_crash: int = -1,
           log_every: int = 10):
-    mesh = make_host_mesh()
+    mesh = data_mesh()
     data_axes = ("data",)
     key = jax.random.PRNGKey(seed)
 

@@ -6,7 +6,8 @@ A run record is a plain dict:
       "schema":  "repro.bench.v1",
       "name":    "engine",               # what produced it
       "git_rev": "35f30c5" | "unknown",
-      "env":     {"backend": "cpu", "devices": 1, "jax": "0.4.x"},
+      "env":     {"backend": "tpu", "device_kind": "TPU v5 lite",
+                  "devices": 1, "jax": "0.9.0"},
       "shapes":  {...},                  # problem sizes (n, d, k, ...)
       "config":  {...},                  # knobs (batch_size, nprobe, ...)
       "metrics": {...},                  # measured numbers
@@ -51,13 +52,12 @@ def git_rev() -> str:
 
 
 def _env() -> Dict[str, Any]:
-    try:
-        import jax
-        return {"backend": jax.default_backend(),
-                "devices": jax.device_count(),
-                "jax": jax.__version__}
-    except Exception:
-        return {"backend": "unknown", "devices": 0, "jax": "unknown"}
+    """The device the record was measured on, as JAX reports it (a JAX
+    failure raises: a record never names an unknown device)."""
+    import jax
+    dev = jax.devices()[0]
+    return {"backend": dev.platform, "device_kind": dev.device_kind,
+            "devices": jax.device_count(), "jax": jax.__version__}
 
 
 def run_record(name: str, *, shapes: Optional[Dict[str, Any]] = None,

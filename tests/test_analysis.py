@@ -271,11 +271,12 @@ os.environ.setdefault("JAX_PLATFORMS", "cpu")
 import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 
 from repro.analysis.contracts import audit_trace
 
-mesh = jax.make_mesh((2,), ("data",))
+mesh = jax.make_mesh((2,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 prog = jax.jit(shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P()))
 low = prog.lower(jnp.zeros((8,), jnp.float32))

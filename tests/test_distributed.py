@@ -30,7 +30,8 @@ X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=3, key=key)
 a0 = two_means_tree(X, k, key)
 st = init_state(X, a0, k)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 epoch = make_sharded_epoch(mesh, batch_size=128)
 dist_fn = sharded_distortion(mesh)
 assign, D, cnt = st.assign, st.D, st.cnt
@@ -81,7 +82,8 @@ for t in range(6):
                       cfg)
 ref = float(distortion(X, st.assign, k))
 
-mesh = jax.make_mesh((8,), ("data",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 epoch = make_sharded_epoch(mesh, batch_size=128)
 assign, D, cnt = a0, *init_state(X, a0, k)[1:3]
 for t in range(6):
@@ -118,7 +120,8 @@ X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=2, key=key)
 G = jnp.maximum(g.ids, 0)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 source = engine.graph_source(G)
 
 for mode in ("bkm", "lloyd"):
@@ -167,7 +170,8 @@ key = jax.random.PRNGKey(0)
 n, d, k = 2048, 16, 32
 X = gmm_blobs(key, n, d, 32)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((4,), ("data",))
+mesh = jax.make_mesh((4,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 Gdummy = jnp.zeros((n, 1), jnp.int32)
 d0 = float(distortion(X, a0, k))
 for kind in ("dense", "probe"):
@@ -213,7 +217,8 @@ X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=2, key=key)
 G = jnp.maximum(g.ids, 0)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 iters = 5
 cfg = engine.EngineConfig(batch_size=128, sparse_updates=True, iters=iters,
                           min_move_frac=-1.0, telemetry=True)
@@ -298,7 +303,8 @@ key = jax.random.PRNGKey(0)
 n, d, R = 2048, 16, 4
 assert len(jax.devices()) == R
 X = gmm_blobs(key, n, d, 32)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 
 # Alg. 3 partition source: bit-exact parity, one host sync per build
 cfg = GraphBuildConfig(kappa=8, xi=32, tau=3, chunk=256, shards=R)
@@ -382,7 +388,8 @@ X = gmm_blobs(key, n, d, 24)
 C = gmm_blobs(jax.random.fold_in(key, 1), k, d, 24)
 a, _ = ref.assign_centroids(X, C)
 index = ivf.build_ivf(X, FakeResult(a, C, k), block_rows=bl)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 sivf = ShardedIvf(mesh, index)
 nq = 32
 Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (nq, d))
@@ -474,7 +481,8 @@ X = gmm_blobs(key, n, d, 24)
 C = gmm_blobs(jax.random.fold_in(key, 1), k, d, 24)
 a, _ = ref.assign_centroids(X, C)
 index = ivf.build_ivf(X, FakeResult(a, C, k), block_rows=bl)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 sivf = ShardedIvf(mesh, index)
 nq = 32
 Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (nq, d))
@@ -543,7 +551,8 @@ X = gmm_blobs(key, n, d, 24)
 C = gmm_blobs(jax.random.fold_in(key, 1), k, d, 24)
 a, _ = ref.assign_centroids(X, C)
 base = ivf.build_ivf(X, FakeResult(a, C, k), block_rows=bl)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 nq = 32
 Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (nq, d))
 
@@ -652,7 +661,7 @@ def test_cluster_large_example_indivisible_n_4dev():
 
 CODE_TREE_PARITY = r"""
 import jax, jax.numpy as jnp, numpy as np
-from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 from repro.data import gmm_blobs
 from repro.core.two_means import two_means_dist, two_means_scan
@@ -662,7 +671,8 @@ n, d, k, R = 2048, 16, 16, 4
 assert len(jax.devices()) == R
 X = gmm_blobs(key, n, d, 32)
 row_ids = jnp.arange(n, dtype=jnp.int32)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 kt = jax.random.fold_in(key, 7)
 
 def body(Xl, rl):
@@ -670,7 +680,7 @@ def body(Xl, rl):
 
 mesh_fn = jax.jit(shard_map(body, mesh=mesh,
                             in_specs=(P("data"), P("data")),
-                            out_specs=P("data"), check_rep=False))
+                            out_specs=P("data"), check_vma=False))
 a_mesh = np.asarray(mesh_fn(X, row_ids))
 a_emu = np.asarray(two_means_dist(X, row_ids, k, kt, shards=R))
 np.testing.assert_array_equal(a_mesh, a_emu)   # bit-exact across topologies
@@ -726,7 +736,8 @@ n2 = -(-n // k) * k
 a0 = two_means_tree(jnp.concatenate([X, X[: n2 - n]]), k, key)[:n]
 st0 = init_state(X, a0, k)
 g = jax.random.randint(key, (n, 8), 0, n, dtype=jnp.int32)
-mesh = jax.make_mesh((R,), ("data",))
+mesh = jax.make_mesh((R,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 iters = 3
 n_pad = -(-n // R) * R
 valid = jnp.arange(n_pad) < n
@@ -747,7 +758,7 @@ for kind, src in (("graph", engine.graph_source(gp)),
 
     stp = engine.BKMState(ap, st0.D, st0.cnt, jnp.int32(0))
     st1, hist1, mhist1, epochs1, final1, _ = jax.device_get(
-        engine.run_inline(Xp, stp, src, key, cfg._replace(shards=R),
+        engine.run(Xp, stp, src, key, cfg._replace(shards=R),
                           valid=valid))
     np.testing.assert_array_equal(assign, st1.assign[:n], err_msg=kind)
     np.testing.assert_array_equal(cnt, st1.cnt, err_msg=kind)

@@ -40,7 +40,8 @@ def test_lower_compile_and_analyse(mesh, cfg, kind, seq, batch):
     coll = rl.collective_bytes(compiled.as_text())
     assert coll["total_bytes"] >= 0  # no collectives on 1x1 mesh is fine
     terms = rl.roofline_terms(cost["flops"], cost.get("bytes accessed", 0),
-                              coll["total_wire_bytes"])
+                              coll["total_wire_bytes"],
+                              device_kind="TPU v5 lite")
     assert terms["bottleneck"] in ("compute", "memory", "collective")
 
 

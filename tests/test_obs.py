@@ -121,7 +121,7 @@ def test_telemetry_off_adds_zero_hlo(setup):
     f32_shape = f"f32[5,{obs_tel.N_F32}]"
 
     def compiled_text(telemetry):
-        f = jax.jit(lambda X, a0, key: engine.run_inline(
+        f = jax.jit(lambda X, a0, key: engine.run(
             X, engine.init_state(X, a0, k), source, key,
             _run_cfg(telemetry)))
         return f.lower(X, a0, key).compile().as_text()
@@ -218,13 +218,15 @@ def test_emit_rejects_drift(tmp_path):
 # obs_report
 # ---------------------------------------------------------------------------
 
-def _kernels_record():
-    return run_record("kernels", metrics={"kernels": [
+def _kernels_record(device_kind="TPU v5 lite"):
+    rec = run_record("kernels", metrics={"kernels": [
         {"kernel": "pairwise_sq", "us": 100.0,
          "shape": {"B": 256, "m": 64, "d": 128}},
         {"kernel": "refine_merge", "us": 50.0,
          "shape": {"B": 4096, "C": 64, "d": 128, "kappa": 16}},
     ]})
+    rec["env"]["device_kind"] = device_kind      # as measured on the chip
+    return rec
 
 
 def test_obs_report_renders_tables(tmp_path, capsys):
@@ -250,6 +252,15 @@ def test_obs_report_fails_on_missing_inventory(tmp_path, capsys):
     write_json(str(tmp_path / "BENCH_kernels.json"), rec)
     assert obs_report.main(["--dir", str(tmp_path)]) != 0
     assert "KERNEL_INVENTORY" in capsys.readouterr().err
+
+
+def test_obs_report_fails_on_unknown_device_kind(tmp_path, capsys):
+    """A kernels record from a device without published peaks (e.g. a CPU
+    run) gets no roofline: the report fails instead of assuming a chip."""
+    from repro.launch import obs_report
+    write_json(str(tmp_path / "BENCH_kernels.json"), _kernels_record("cpu"))
+    assert obs_report.main(["--dir", str(tmp_path)]) != 0
+    assert "no published peaks" in capsys.readouterr().err
 
 
 def test_obs_report_fails_on_drift_and_missing_required(tmp_path, capsys):

@@ -34,13 +34,20 @@ def test_collective_parser_kinds_and_bytes():
 
 
 def test_roofline_terms_bottleneck():
-    t = rl.roofline_terms(flops=197e12, hbm_bytes=0, coll_bytes=0)
+    v5e = dict(device_kind="TPU v5 lite")
+    t = rl.roofline_terms(flops=197e12, hbm_bytes=0, coll_bytes=0, **v5e)
     assert t["bottleneck"] == "compute"
     assert t["compute_s"] == pytest.approx(1.0)
-    t = rl.roofline_terms(flops=0, hbm_bytes=819e9, coll_bytes=0)
+    t = rl.roofline_terms(flops=0, hbm_bytes=819e9, coll_bytes=0, **v5e)
     assert t["bottleneck"] == "memory"
-    t = rl.roofline_terms(flops=0, hbm_bytes=0, coll_bytes=150e9)
+    t = rl.roofline_terms(flops=0, hbm_bytes=0, coll_bytes=150e9, **v5e)
     assert t["bottleneck"] == "collective"
+
+
+def test_roofline_unknown_device_kind_raises():
+    with pytest.raises(KeyError, match="no published peaks"):
+        rl.roofline_terms(flops=1.0, hbm_bytes=1.0, coll_bytes=0.0,
+                          device_kind="cpu")
 
 
 def test_cost_analysis_is_per_partition():

@@ -95,7 +95,10 @@ def test_examples_converge(script, args):
     import subprocess
     import sys
     root = os.path.join(os.path.dirname(__file__), "..")
+    # one device: forced host devices another test left in XLA_FLAGS would
+    # make cluster_large shard (and refuse a k the mesh does not divide)
     env = dict(os.environ, PYTHONPATH=os.path.join(root, "src"))
+    env.pop("XLA_FLAGS", None)
     r = subprocess.run([sys.executable, os.path.join(root, script)] + args,
                        capture_output=True, text=True, env=env, timeout=900)
     assert r.returncode == 0, (r.stdout[-2000:], r.stderr[-3000:])
