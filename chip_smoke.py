@@ -214,6 +214,19 @@ def kernel_parity(key) -> None:
     index = ivf.quantize_index(ivf.build_ivf(Xc, Clustering), "pq",
                                key=ks[0])
     Q = Xc[:256] + 0.05
+    # the f32 scan in query tiles of 64 (4 kernel calls), as a serving batch
+    # larger than one VMEM tile runs
+    cids, _ = ref.probe_centroids(Q, C_, 4)
+    tm = ivf.build_tile_map(cids, index.starts, index.caps,
+                            max_tiles=index.max_list_tiles,
+                            block_rows=index.block_rows,
+                            null_tile=index.null_tile)
+    scan = functools.partial(ops.ivf_scan, Q, index.vecs, index.ids, tm,
+                             block_rows=index.block_rows, topk=TOPK)
+    agree = float(np.mean(np.asarray(scan(tile=64)[0])
+                          == np.asarray(scan(force="ref")[0])))
+    check(agree > 0.99, f"ivf_scan in query tiles of 64 vs ref: {agree:.4f} "
+                        f"of ids equal")
     for codec in ("f32", "pq"):
         a, _ = ivf.search(index, Q, topk=TOPK, nprobe=4, codec=codec)
         b, _ = ivf.search(index, Q, topk=TOPK, nprobe=4, codec=codec,

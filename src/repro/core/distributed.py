@@ -68,6 +68,7 @@ from jax import shard_map
 from repro.core.engine import (CandidateSource, EngineConfig, dense_source,
                                graph_source, probe_source,
                                sharded_epoch_body, sharded_run_body)
+from repro.kernels.ref import HIGHEST
 
 DATA_AXES = ("data",)
 
@@ -388,7 +389,8 @@ class ShardedIvf:
             Qf = Q.astype(jnp.float32)
             Cf = cslab_l.astype(jnp.float32)
             csq = jnp.sum(Cf * Cf, axis=-1)
-            part = csq[None, :] - 2.0 * (Qf @ Cf.T)      # (q, k_slab)
+            part = csq[None, :] - 2.0 * jnp.matmul(      # (q, k_slab)
+                Qf, Cf.T, precision=HIGHEST)
             part = jnp.where((ccid_l >= 0)[None, :], part, jnp.inf)
             d_l, i_l = stable_topk(
                 part, jnp.broadcast_to(ccid_l, part.shape),

@@ -172,7 +172,8 @@ def _score_from_rows(xb, u, cand, rows, cnt, mode, eps):
     it with a plain ``D[cand]`` gather — element-for-element the same
     values, so the two topologies share every downstream flop bit-exactly.
     """
-    dots = jnp.einsum("bd,bcd->bc", xb, rows)            # (B, C)
+    dots = jnp.einsum("bd,bcd->bc", xb, rows,
+                      precision=kref.HIGHEST)              # (B, C)
     dsq = jnp.sum(rows * rows, axis=-1)                  # (B, C)
     xsq = jnp.sum(xb * xb, axis=-1)                      # (B,)
     nv = cnt[cand]                                       # (B, C)
@@ -203,7 +204,7 @@ def _score_dense(xb, u, D, cnt, mode, eps):
     """Best move per sample over ALL k clusters, via one matmul (MXU path)."""
     k = D.shape[0]
     dsq = jnp.sum(D * D, axis=-1)                        # (k,)
-    dots = xb @ D.T                                      # (B, k)
+    dots = jnp.matmul(xb, D.T, precision=kref.HIGHEST)   # (B, k)
     xsq = jnp.sum(xb * xb, axis=-1)                      # (B,)
     if mode == "bkm":
         nv = cnt[None, :]
@@ -334,7 +335,8 @@ def _probe_sharded(xb, D_loc, cnt, coff, p, comm: _Comm):
     cnt_loc = jax.lax.dynamic_slice(cnt, (coff,), (k_loc,))
     C_loc = D_loc / jnp.maximum(cnt_loc, 1.0)[:, None]
     csq = jnp.sum(C_loc * C_loc, axis=-1)
-    part = csq[None, :] - 2.0 * (xa @ C_loc.T)           # (R*B, k_loc)
+    part = csq[None, :] - 2.0 * jnp.matmul(           # (R*B, k_loc)
+        xa, C_loc.T, precision=kref.HIGHEST)
     ids0 = jnp.broadcast_to(coff + jnp.arange(k_loc, dtype=jnp.int32),
                             part.shape)
     d_l, i_l = kref.stable_topk(part, ids0, min(p, k_loc))
@@ -364,7 +366,7 @@ def _dense_block_scores(xa, ua, D_blk, cnt, coff_blk, mode):
     k_loc = D_blk.shape[0]
     ids_loc = coff_blk + jnp.arange(k_loc, dtype=jnp.int32)
     dsq = jnp.sum(D_blk * D_blk, axis=-1)                # (k_loc,)
-    dots = xa @ D_blk.T                                  # (R*B, k_loc)
+    dots = jnp.matmul(xa, D_blk.T, precision=kref.HIGHEST)  # (R*B, k_loc)
     xsq = jnp.sum(xa * xa, axis=-1)
     nv = cnt[ids_loc][None, :]
     is_self = ids_loc[None, :] == ua[:, None]

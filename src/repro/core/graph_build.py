@@ -60,6 +60,7 @@ from repro.core import engine
 from repro.core.knn_graph import KnnGraph, members_table_local, merge_topk
 from repro.core.two_means import _TreeTopo, two_means_dist
 from repro.kernels import ops as kops
+from repro.kernels.ref import HIGHEST
 from repro.obs import telemetry as obs_tel
 
 
@@ -197,7 +198,8 @@ def _guided_stats(X, assign, k0, topo: _TreeTopo):
     Xf = X.astype(jnp.float32)
     onehot = (assign[:, None] == jnp.arange(k0, dtype=jnp.int32)[None, :]
               ).astype(jnp.float32)
-    D_T = topo.fsum_blocks(lambda xb, ob: xb.T @ ob, Xf, onehot)
+    D_T = topo.fsum_blocks(
+        lambda xb, ob: jnp.matmul(xb.T, ob, precision=HIGHEST), Xf, onehot)
     cnt = topo.isum(jax.ops.segment_sum(jnp.ones(assign.shape, jnp.int32),
                                         assign, num_segments=k0))
     return D_T, cnt.astype(jnp.float32)

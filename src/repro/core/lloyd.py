@@ -8,6 +8,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops as kops
+from repro.kernels.ref import HIGHEST
 
 
 def init_random(X: jax.Array, k: int, key: jax.Array) -> jax.Array:
@@ -23,7 +24,8 @@ def init_kmeanspp(X: jax.Array, k: int, key: jax.Array) -> jax.Array:
     xsq = jnp.sum(Xf * Xf, axis=-1)
     first = jax.random.randint(key, (), 0, n)
     C = jnp.zeros((k, d), jnp.float32).at[0].set(Xf[first])
-    d2 = xsq + jnp.sum(Xf[first] ** 2) - 2.0 * (Xf @ Xf[first])
+    d2 = (xsq + jnp.sum(Xf[first] ** 2)
+          - 2.0 * jnp.matmul(Xf, Xf[first], precision=HIGHEST))
     d2 = jnp.maximum(d2, 0.0)
 
     def body(i, carry):
@@ -33,7 +35,7 @@ def init_kmeanspp(X: jax.Array, k: int, key: jax.Array) -> jax.Array:
         nxt = jax.random.choice(kk, n, p=p)
         c = Xf[nxt]
         C = C.at[i].set(c)
-        nd = xsq + jnp.sum(c * c) - 2.0 * (Xf @ c)
+        nd = xsq + jnp.sum(c * c) - 2.0 * jnp.matmul(Xf, c, precision=HIGHEST)
         return C, jnp.minimum(d2, jnp.maximum(nd, 0.0))
 
     C, _ = jax.lax.fori_loop(1, k, body, (C, d2))

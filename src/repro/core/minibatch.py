@@ -9,6 +9,7 @@ import jax.numpy as jnp
 
 from repro.core.lloyd import init_random
 from repro.kernels import ops as kops
+from repro.kernels.ref import HIGHEST
 
 
 @functools.partial(jax.jit, static_argnums=(3, 4))
@@ -22,7 +23,9 @@ def _steps(X, C, key, batch_size: int, steps: int):
                                  (batch_size,), 0, n)
         xb = X[idx].astype(jnp.float32)
         csq = jnp.sum(C * C, axis=-1)
-        a = jnp.argmin(csq[None, :] - 2.0 * (xb @ C.T), axis=-1)
+        a = jnp.argmin(
+            csq[None, :] - 2.0 * jnp.matmul(xb, C.T, precision=HIGHEST),
+            axis=-1)
         bs = jax.ops.segment_sum(jnp.ones((batch_size,), jnp.float32), a,
                                  num_segments=k)
         bsum = jax.ops.segment_sum(xb, a, num_segments=k)

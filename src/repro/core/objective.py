@@ -19,6 +19,8 @@ from typing import NamedTuple, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import HIGHEST
+
 
 class ClusterStats(NamedTuple):
     """Sufficient statistics of a clustering: composite vectors + counts."""
@@ -116,7 +118,8 @@ def assignment_distortion(X: jax.Array, C: jax.Array, block: int = 2048
     csq = jnp.sum(C.astype(jnp.float32) ** 2, axis=-1)
 
     def body(xb):
-        dots = xb.astype(jnp.float32) @ C.astype(jnp.float32).T
+        dots = jnp.matmul(xb.astype(jnp.float32), C.astype(jnp.float32).T,
+                          precision=HIGHEST)
         d2 = csq[None, :] - 2.0 * dots
         a = jnp.argmin(d2, axis=-1)
         best = jnp.min(d2, axis=-1) + jnp.sum(xb.astype(jnp.float32) ** 2, -1)

@@ -6,6 +6,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import HIGHEST
+
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
 def brute_force_knn(X: jax.Array, kappa: int, chunk: int = 1024) -> jax.Array:
@@ -17,7 +19,7 @@ def brute_force_knn(X: jax.Array, kappa: int, chunk: int = 1024) -> jax.Array:
     def body(args):
         xb, base = args
         d2 = (jnp.sum(xb * xb, -1)[:, None] + sq[None, :]
-              - 2.0 * (xb @ Xf.T))                       # (c, n)
+              - 2.0 * jnp.matmul(xb, Xf.T, precision=HIGHEST))  # (c, n)
         own = base + jnp.arange(xb.shape[0])
         d2 = d2.at[jnp.arange(xb.shape[0]), own].set(jnp.inf)
         _, ids = jax.lax.top_k(-d2, kappa)

@@ -27,6 +27,8 @@ from typing import Tuple
 import jax
 import jax.numpy as jnp
 
+from repro.kernels.ref import HIGHEST
+
 
 def _is_pow2(v: int) -> bool:
     return v > 0 and (v & (v - 1)) == 0
@@ -248,7 +250,8 @@ def _radix_left(ukey, pos_u, seg, k, r, active, topo: _TreeTopo):
         # shapes this layout avoids.  f32 accumulation is exact for counts
         # below 2^24 (n_glob is asserted against that bound).
         tri = jnp.tril(jnp.ones((256, 256), jnp.float32))
-        cum = (tri @ hist.astype(jnp.float32)).astype(jnp.int32)
+        cum = jnp.matmul(tri, hist.astype(jnp.float32),
+                         precision=HIGHEST).astype(jnp.int32)
         dstar = jnp.argmax(cum > r[None, :], axis=0).astype(jnp.int32)
         below = jnp.take_along_axis(cum - hist, dstar[None, :], 0)[0]
         ds_row = dstar[seg]
@@ -295,14 +298,17 @@ def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
 
     def seed_vec_T(pos_c):
         mask = (pos_u[:, None] == pos_c[None, :]).astype(jnp.float32)
-        return topo.owner_fsum(Xf.T @ mask)                  # (d, k)
+        return topo.owner_fsum(
+            jnp.matmul(Xf.T, mask, precision=HIGHEST))       # (d, k)
 
     def level(seg, lvl):
         m = jnp.int32(n_glob) >> lvl
         half = m >> 1
         onehot = (seg[:, None] == jnp.arange(k, dtype=jnp.int32)[None, :]
                   ).astype(jnp.float32)                      # (B, k)
-        tot_T = topo.fsum_blocks(lambda xb, ob: xb.T @ ob, Xf, onehot)
+        tot_T = topo.fsum_blocks(
+            lambda xb, ob: jnp.matmul(xb.T, ob, precision=HIGHEST),
+            Xf, onehot)
         cntc = topo.seg_isum(jnp.ones(seg.shape, jnp.int32), seg, k)
 
         kl = jax.random.fold_in(key, lvl)
@@ -332,7 +338,8 @@ def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
             w = _radix_left(ukey, pos_u, seg, k, r_half, all_rows, topo
                             ).astype(jnp.float32)
             s1_T = topo.fsum_blocks(
-                lambda xb, ob, wb: xb.T @ (ob * wb[:, None]), Xf, onehot, w)
+                lambda xb, ob, wb: jnp.matmul(xb.T, ob * wb[:, None],
+                                              precision=HIGHEST), Xf, onehot, w)
             n1 = topo.seg_isum(w.astype(jnp.int32), seg, k)
             n1f = jnp.maximum(n1, 1).astype(jnp.float32)
             n2f = jnp.maximum(cntc - n1, 1).astype(jnp.float32)

@@ -130,7 +130,7 @@ def exact_rerank(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
     safe = jnp.clip(pos, 0)
     cv = vecs[safe].astype(jnp.float32)                    # (q, R, d)
     vsq = jnp.sum(cv * cv, axis=-1)                        # (q, R)
-    dots = jnp.einsum("qd,qrd->qr", qf, cv)
+    dots = jnp.einsum("qd,qrd->qr", qf, cv, precision=kref.HIGHEST)
     cids = jnp.where(pos < 0, -1, pids.astype(jnp.int32)[safe])
     part = jnp.where(cids < 0, jnp.inf, vsq - 2.0 * dots)
     d, ids = kref.stable_topk(part, cids, topk)

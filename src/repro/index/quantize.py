@@ -171,7 +171,8 @@ def _encode_pq(codebook: jax.Array, X: jax.Array) -> jax.Array:
     Xs = X.reshape(X.shape[0], nsub, dsub)
     # ||x_m - book_m||^2 up to the x^2 term, argmin ties -> lowest code
     d2 = (jnp.sum(codebook ** 2, axis=-1)[None]
-          - 2.0 * jnp.einsum("nmd,mvd->nmv", Xs, codebook))
+          - 2.0 * jnp.einsum("nmd,mvd->nmv", Xs, codebook,
+                             precision=kref.HIGHEST))
     return jnp.argmin(d2, axis=-1).astype(jnp.uint8)
 
 
@@ -212,9 +213,10 @@ def build_lut(codec: Codec, Q: jax.Array) -> tuple[jax.Array, jax.Array]:
     Q = jnp.asarray(Q, dtype=jnp.float32)
     if codec.kind == "int8":
         lut = (-2.0 * Q * codec.scale[None, :])[:, :, None]  # (q, d, 1)
-        return lut, -2.0 * (Q @ codec.zero)
+        return lut, -2.0 * jnp.matmul(Q, codec.zero, precision=kref.HIGHEST)
     Qs = Q.reshape(Q.shape[0], codec.nsub, codec.dsub)
-    lut = -2.0 * jnp.einsum("qmd,mvd->qmv", Qs, codec.codebook)
+    lut = -2.0 * jnp.einsum("qmd,mvd->qmv", Qs, codec.codebook,
+                            precision=kref.HIGHEST)
     return lut, jnp.zeros((Q.shape[0],), dtype=jnp.float32)
 
 

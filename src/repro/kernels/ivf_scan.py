@@ -15,8 +15,9 @@ probed fraction of the database is ever touched.
 # autotune: exempt(ivf_scan_grouped): the block_rows tile shape is an
 #   index-format constant chosen at pack time, and the group size G is a
 #   recall/locality knob owned by the caller, not a dispatch-time tile.
-#   (ivf_scan itself IS swept: its `tile` chunks the reference's query axis
-#   — cache blocking, bitwise-neutral — resolved from autotune_table.json.)
+#   (ivf_scan itself IS swept: its `tile` chunks the query axis — the
+#   reference's for cache blocking, the kernel's under its VMEM budget;
+#   bitwise-neutral — resolved from autotune_table.json.)
 from __future__ import annotations
 
 import functools
@@ -28,6 +29,46 @@ from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
 from repro.kernels.centroid_assign import _select_topk
+
+# VMEM the whole-array query-axis blocks of one call may take, double-
+# buffered (the (bq, d) queries and the two (bq, topk) outputs), leaving the
+# streamed list tiles room under the 16 MiB scoped-VMEM limit of a TPU core.
+QUERY_BYTES = 4 << 20
+# SMEM the scalar-prefetched tile map of one call may take (a TPU core has
+# 1 MiB of SMEM; the map is passed flat, as a 2-D SMEM array pads its rows
+# to 128 words).
+TILE_MAP_BYTES = 256 << 10
+
+
+def _lanes(w: int) -> int:
+    return -(-w // 128) * 128
+
+
+def q_tile(nq: int, width: int, topk: int, T: int) -> int:
+    """Largest 8-aligned query tile whose whole-array blocks — ``width`` f32
+    lanes of query input per query (0 for none) and two (·, topk) outputs,
+    double-buffered — fit ``QUERY_BYTES``, and whose T-tile map fits
+    ``TILE_MAP_BYTES`` (never more than the 8-padded batch)."""
+    per_q = 2 * 4 * ((_lanes(width) if width else 0) + 2 * _lanes(topk))
+    fit = min(QUERY_BYTES // per_q, TILE_MAP_BYTES // (4 * T))
+    return min(max(fit // 8 * 8, 8), -(-nq // 8) * 8)
+
+
+def map_query_tiles(fn, bq: int, *arrays):
+    """``fn(*arrays)`` over consecutive tiles of ``bq`` queries (the arrays
+    share their leading query axis), one ``lax.map`` step per tile, so the
+    kernel never holds more than ``bq`` queries in VMEM.  Queries are
+    independent, so the tiling is bitwise-neutral; a ragged tail is padded
+    with zero queries scanning tile 0 and sliced off."""
+    nq = arrays[0].shape[0]
+    nt = -(-nq // bq)
+    if nt == 1:
+        return fn(*arrays)
+    pad = nt * bq - nq
+    tiles = [jnp.pad(a, [(0, pad)] + [(0, 0)] * (a.ndim - 1))
+             .reshape(nt, bq, *a.shape[1:]) for a in arrays]
+    out = jax.lax.map(lambda xs: fn(*xs), tiles)
+    return jax.tree.map(lambda o: o.reshape(nt * bq, *o.shape[2:])[:nq], out)
 
 
 def tile_scores(q, v_ref, id_ref):
@@ -73,10 +114,11 @@ def _kernel(tile_map_ref, q_ref, v_ref, id_ref, oid_ref, od_ref, *,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_rows", "topk", "interpret", "raw"))
+                   static_argnames=("block_rows", "topk", "interpret", "raw",
+                                    "bq"))
 def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
              tile_map: jax.Array, *, block_rows: int, topk: int = 10,
-             interpret: bool = False, raw: bool = False):
+             interpret: bool = False, raw: bool = False, bq: int = 0):
     """Scan each query's probed tiles of the packed database.
 
     Q: (q, d) queries; vecs: (n_pad, d) packed vectors (n_pad a multiple of
@@ -89,33 +131,41 @@ def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
     ``raw=True`` skips the final ``+ ||q||^2`` / clamp and returns the
     kernel's partial distances (+inf at invalid slots) — mesh shards merge
     on these so cross-shard selection is bit-identical to a single scan.
+    ``bq`` is the query tile (0 = the whole batch), rounded up to a multiple
+    of 8 and capped by ``q_tile``'s VMEM and SMEM budgets; larger batches
+    run one kernel call per tile (``map_query_tiles``).
     """
     nq, d = Q.shape
-    n_pad = vecs.shape[0]
-    assert n_pad % block_rows == 0, (n_pad, block_rows)
+    assert vecs.shape[0] % block_rows == 0, (vecs.shape, block_rows)
     assert tile_map.shape[0] == nq
     T = tile_map.shape[1]
+    bq = min(-(-(bq or nq) // 8) * 8, q_tile(nq, d, topk, T))
 
-    whole = lambda shape: pl.BlockSpec(shape, lambda i, t, tm: (0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq, T),
-        in_specs=[
-            whole((nq, d)),
-            pl.BlockSpec((block_rows, d), lambda i, t, tm: (tm[i, t], 0)),
-            pl.BlockSpec((1, block_rows), lambda i, t, tm: (0, tm[i, t])),
-        ],
-        out_specs=[whole((nq, topk)), whole((nq, topk))],
-    )
-    oid, od = pl.pallas_call(
-        functools.partial(_kernel, topk=topk),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, topk), jnp.int32),
-            jax.ShapeDtypeStruct((nq, topk), jnp.float32),
-        ],
-        interpret=interpret,
-    )(tile_map.astype(jnp.int32), Q, vecs, pids.astype(jnp.int32)[None, :])
+    def scan(q, tm):                            # one tile of queries
+        whole = lambda shape: pl.BlockSpec(shape, lambda i, t, tm: (0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(q.shape[0], T),
+            in_specs=[
+                whole(q.shape),
+                pl.BlockSpec((block_rows, d),
+                             lambda i, t, tm: (tm[i * T + t], 0)),
+                pl.BlockSpec((1, block_rows),
+                             lambda i, t, tm: (0, tm[i * T + t])),
+            ],
+            out_specs=[whole((q.shape[0], topk))] * 2,
+        )
+        return pl.pallas_call(
+            functools.partial(_kernel, topk=topk),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((q.shape[0], topk), jnp.int32),
+                jax.ShapeDtypeStruct((q.shape[0], topk), jnp.float32),
+            ],
+            interpret=interpret,
+        )(tm.reshape(-1), q, vecs, pids.astype(jnp.int32)[None, :])
+
+    oid, od = map_query_tiles(scan, bq, Q, tile_map.astype(jnp.int32))
     if raw:
         return oid, jnp.where(oid < 0, jnp.inf, od)
     return _ref.finalize_d2(oid, od, Q)

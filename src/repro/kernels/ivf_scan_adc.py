@@ -32,11 +32,11 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from repro.kernels import ref as _ref
-from repro.kernels.ivf_scan import running_topk
+from repro.kernels.ivf_scan import map_query_tiles, q_tile, running_topk
 
 
 def _kernel(tile_map_ref, lut_ref, vn_ref, code_ref, id_ref, opos_ref,
-            od_ref, *, block_rows: int, topk: int, width: int):
+            od_ref, *, block_rows: int, topk: int, width: int, n_t: int):
     i = pl.program_id(0)
     t = pl.program_id(1)
     lut = lut_ref[...].astype(jnp.float32)      # (1, M, W), VMEM-resident
@@ -47,11 +47,12 @@ def _kernel(tile_map_ref, lut_ref, vn_ref, code_ref, id_ref, opos_ref,
     ex = _ref.adc_expand(code_ref[...], width)  # (bl, M*W)
     cross = jax.lax.dot_general(
         lf, ex, (((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)     # (1, bl)
     part = vn + cross                           # ||v̂||² - 2 q.v̂
     part = jnp.where(ids < 0, jnp.inf, part)
 
-    tile = tile_map_ref[i, t]                   # scalar prefetch: SMEM read
+    tile = tile_map_ref[i * n_t + t]            # scalar prefetch: SMEM read
     pos = (tile * block_rows
            + jax.lax.broadcasted_iota(jnp.int32, (1, block_rows), 1))
     pos = jnp.where(ids < 0, -1, pos)           # (1, bl) packed positions
@@ -59,10 +60,11 @@ def _kernel(tile_map_ref, lut_ref, vn_ref, code_ref, id_ref, opos_ref,
 
 
 @functools.partial(jax.jit,
-                   static_argnames=("block_rows", "topk", "interpret"))
+                   static_argnames=("block_rows", "topk", "interpret", "bq"))
 def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
                  codes: jax.Array, pids: jax.Array, tile_map: jax.Array, *,
-                 block_rows: int, topk: int = 10, interpret: bool = False):
+                 block_rows: int, topk: int = 10, interpret: bool = False,
+                 bq: int = 0):
     """Scan each query's probed tiles of the CODE slab via its VMEM LUT.
 
     lut: (q, M, W) f32 per-query table and qconst: (q,) per-query constant
@@ -71,7 +73,9 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
     padding); tile_map: (q, T) int32.  ``qconst`` is identical for every
     candidate of a query, hence rank-invariant: the kernel selects on the
     LUT partials alone and the constant is added to the selected values
-    outside the grid (same op order as the ref oracle).
+    outside the grid (same op order as the ref oracle).  ``bq`` is the
+    query tile, as in `ivf_scan` (the LUT block is per query, so only the
+    (·, topk) outputs and the tile map count against its budgets).
 
     Returns (ids (q, topk) int32, pos (q, topk) int32 packed-row positions,
     part (q, topk) f32 RAW partials ascending, +inf at empty slots) — the
@@ -79,36 +83,41 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
     the raw partials exactly as with `ivf_scan(raw=True)`.
     """
     nq, m, w = lut.shape
-    n_pad = codes.shape[0]
-    assert n_pad % block_rows == 0, (n_pad, block_rows)
-    assert codes.shape[1] == m and vnorm.shape[0] == n_pad
+    assert codes.shape[0] % block_rows == 0, (codes.shape, block_rows)
+    assert codes.shape[1] == m and vnorm.shape[0] == codes.shape[0]
     assert tile_map.shape[0] == nq
     T = tile_map.shape[1]
+    bq = min(-(-(bq or nq) // 8) * 8, q_tile(nq, 0, topk, T))
 
-    tiled_row = pl.BlockSpec((1, block_rows), lambda i, t, tm: (0, tm[i, t]))
-    whole = pl.BlockSpec((nq, topk), lambda i, t, tm: (0, 0))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(nq, T),
-        in_specs=[
-            pl.BlockSpec((1, m, w), lambda i, t, tm: (i, 0, 0)),
-            tiled_row,
-            pl.BlockSpec((block_rows, m), lambda i, t, tm: (tm[i, t], 0)),
-            tiled_row,
-        ],
-        out_specs=[whole, whole],
-    )
-    opos, od = pl.pallas_call(
-        functools.partial(_kernel, block_rows=block_rows, topk=topk,
-                          width=w),
-        grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((nq, topk), jnp.int32),
-            jax.ShapeDtypeStruct((nq, topk), jnp.float32),
-        ],
-        interpret=interpret,
-    )(tile_map.astype(jnp.int32),
-      lut.astype(jnp.float32), vnorm[None, :], codes,
-      pids.astype(jnp.int32)[None, :])
+    def scan(lt, tm):                           # one tile of queries
+        tiled_row = pl.BlockSpec((1, block_rows),
+                                 lambda i, t, tm: (0, tm[i * T + t]))
+        whole = pl.BlockSpec((lt.shape[0], topk), lambda i, t, tm: (0, 0))
+        grid_spec = pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(lt.shape[0], T),
+            in_specs=[
+                pl.BlockSpec((1, m, w), lambda i, t, tm: (i, 0, 0)),
+                tiled_row,
+                pl.BlockSpec((block_rows, m),
+                             lambda i, t, tm: (tm[i * T + t], 0)),
+                tiled_row,
+            ],
+            out_specs=[whole, whole],
+        )
+        return pl.pallas_call(
+            functools.partial(_kernel, block_rows=block_rows, topk=topk,
+                              width=w, n_t=T),
+            grid_spec=grid_spec,
+            out_shape=[
+                jax.ShapeDtypeStruct((lt.shape[0], topk), jnp.int32),
+                jax.ShapeDtypeStruct((lt.shape[0], topk), jnp.float32),
+            ],
+            interpret=interpret,
+        )(tm.reshape(-1), lt, vnorm[None, :], codes,
+          pids.astype(jnp.int32)[None, :])
+
+    opos, od = map_query_tiles(scan, bq, lut.astype(jnp.float32),
+                               tile_map.astype(jnp.int32))
     ids = jnp.where(opos < 0, -1, pids.astype(jnp.int32)[jnp.clip(opos, 0)])
     return ids, opos, jnp.where(opos < 0, jnp.inf, od + qconst[:, None])

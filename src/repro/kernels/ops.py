@@ -116,9 +116,9 @@ def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
              tile: int | None = None):
     """Per-query scan of probed packed-list tiles -> (ids, d2) top-k.
 
-    ``tile`` chunks the reference's query axis (cache blocking, bitwise-
-    neutral — see ``ref.ivf_scan``); the Pallas grid is already per-query,
-    so the TPU path ignores it.
+    ``tile`` chunks the query axis, bitwise-neutral: the reference's for
+    cache blocking (see ``ref.ivf_scan``), the Pallas kernel's to cap the
+    queries its VMEM holds (0 = its VMEM budget, ``ivf_scan.q_tile``).
     """
     with kernel_scope("ivf_scan"):
         nq, d = Q.shape
@@ -131,7 +131,7 @@ def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
                                  tile=t)
         return _ivf.ivf_scan(Q, vecs, pids, tile_map, block_rows=block_rows,
                              topk=topk, interpret=(force == "interpret"),
-                             raw=raw)
+                             raw=raw, bq=t)
 
 
 def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
@@ -143,9 +143,9 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
     (lut (q, M, W), qconst (q,)) from ``index.quantize.build_lut`` (W=256
     pq, W=1 int8); codes/vnorm are the packed u8 slab and reconstruction
     norms.  Returns (ids, packed-row pos, RAW partials) — callers finalize
-    or exact-rerank.  ``tile`` chunks the reference's query axis (bitwise-
-    neutral); the Pallas grid is per-query and keeps the (1, M, W) LUT
-    block VMEM-resident.
+    or exact-rerank.  ``tile`` chunks the query axis as in ``ivf_scan``
+    (bitwise-neutral); the Pallas grid is per-query and keeps the (1, M, W)
+    LUT block VMEM-resident.
     """
     with kernel_scope("ivf_scan_adc"):
         nq, m, w = lut.shape
@@ -158,7 +158,7 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
                                      topk=topk, tile=t)
         return _adc.ivf_scan_adc(lut, qconst, vnorm, codes, pids, tile_map,
                                  block_rows=block_rows, topk=topk,
-                                 interpret=(force == "interpret"))
+                                 interpret=(force == "interpret"), bq=t)
 
 
 def ivf_scan_grouped(Qg: jax.Array, vecs: jax.Array, pids: jax.Array,

@@ -38,6 +38,7 @@ def _kernel(x_ref, xt_ref, out_ref):
 
     dots = jax.lax.dot_general(
         x, xt, (((2,), (2,)), ((0,), (0,))),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)   # (bB, m, m)
     sq = jnp.sum(x * x, axis=-1)              # (bB, m)
     partial = sq[:, :, None] + sq[:, None, :] - 2.0 * dots

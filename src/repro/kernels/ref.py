@@ -6,6 +6,11 @@ import functools
 import jax
 import jax.numpy as jnp
 
+# Every matmul of the system's float32 statistics asks for full float32
+# precision: XLA:TPU would otherwise run it as one bfloat16 pass (the ΔI
+# move scores cancel heavily).  XLA:CPU computes float32 either way.
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def pairwise_sq(Xb: jax.Array, *, tile: int = 0) -> jax.Array:
     """Batched squared-L2 distance matrix.
@@ -20,7 +25,8 @@ def pairwise_sq(Xb: jax.Array, *, tile: int = 0) -> jax.Array:
 
     def block(Xf):
         sq = jnp.sum(Xf * Xf, axis=-1)                     # (B', m)
-        dots = jnp.einsum("bid,bjd->bij", Xf, Xf)          # (B', m, m)
+        dots = jnp.einsum("bid,bjd->bij", Xf, Xf,
+                          precision=HIGHEST)              # (B', m, m)
         d2 = sq[:, :, None] + sq[:, None, :] - 2.0 * dots
         return jnp.maximum(d2, 0.0)
 
@@ -68,7 +74,7 @@ def probe_centroids(X: jax.Array, C: jax.Array, p: int):
     Xf = X.astype(jnp.float32)
     Cf = C.astype(jnp.float32)
     csq = jnp.sum(Cf * Cf, axis=-1)
-    part = csq[None, :] - 2.0 * (Xf @ Cf.T)                # (n, k)
+    part = csq[None, :] - 2.0 * jnp.matmul(Xf, Cf.T, precision=HIGHEST)
     d, ids = stable_topk(part, jnp.broadcast_to(
         jnp.arange(C.shape[0], dtype=jnp.int32), part.shape), p)
     xsq = jnp.sum(Xf * Xf, axis=-1)
@@ -125,7 +131,7 @@ def ivf_scan(Q: jax.Array, vecs: jax.Array, pids: jax.Array,
         cids = pids[pos]                                    # (c, L)
         cv = vecs[pos].astype(jnp.float32)                  # (c, L, d)
         vsq = jnp.sum(cv * cv, axis=-1)                     # (c, L)
-        dots = jnp.einsum("qd,qld->ql", qf, cv)
+        dots = jnp.einsum("qd,qld->ql", qf, cv, precision=HIGHEST)
         part = jnp.where(cids < 0, jnp.inf, vsq - 2.0 * dots)
         return stable_topk(part, cids, topk)
 
@@ -179,7 +185,7 @@ def ivf_scan_grouped(Qg: jax.Array, vecs: jax.Array, pids: jax.Array,
             pos = t * block_rows + jnp.arange(block_rows, dtype=jnp.int32)
             cv = vecs[pos].astype(jnp.float32)              # (bl, d)
             dots = jax.lax.dot_general(
-                qf, cv, (((1,), (1,)), ((), ())),
+                qf, cv, (((1,), (1,)), ((), ())), precision=HIGHEST,
                 preferred_element_type=jnp.float32)         # (G, bl)
             vsq = jnp.sum(cv * cv, axis=-1)                 # (bl,)
             return vsq[None, :] - 2.0 * dots, pids[pos]
@@ -275,7 +281,7 @@ def ivf_scan_adc(lut: jax.Array, qconst: jax.Array, vnorm: jax.Array,
                    + jnp.arange(block_rows, dtype=jnp.int32))   # (c, bl)
             ex = adc_expand(codes[pos], W)                  # (c, bl, MW)
             cross = jax.lax.dot_general(
-                lf, ex, (((1,), (2,)), ((0,), (0,))),
+                lf, ex, (((1,), (2,)), ((0,), (0,))), precision=HIGHEST,
                 preferred_element_type=jnp.float32)         # (c, bl)
             return cross, pos
 
@@ -322,11 +328,11 @@ def batched_gather_dots(xf: jax.Array, rows: jax.Array, src: jax.Array,
         xf = jnp.concatenate([xf, xf], axis=0)
         rows = jnp.concatenate([rows, rows], axis=0)
         return jax.lax.dot_general(
-            xf, src[rows], (((1,), (2,)), ((0,), (0,))),
+            xf, src[rows], (((1,), (2,)), ((0,), (0,))), precision=HIGHEST,
             preferred_element_type=jnp.float32)[:1]
     if not tile or tile >= B:
         return jax.lax.dot_general(
-            xf, src[rows], (((1,), (2,)), ((0,), (0,))),
+            xf, src[rows], (((1,), (2,)), ((0,), (0,))), precision=HIGHEST,
             preferred_element_type=jnp.float32)
     tile = max(tile, 2)
     nt = -(-B // tile)
@@ -337,7 +343,7 @@ def batched_gather_dots(xf: jax.Array, rows: jax.Array, src: jax.Array,
     def one(args):
         xt, rt = args
         return jax.lax.dot_general(
-            xt, src[rt], (((1,), (2,)), ((0,), (0,))),
+            xt, src[rt], (((1,), (2,)), ((0,), (0,))), precision=HIGHEST,
             preferred_element_type=jnp.float32)
 
     dots = jax.lax.map(one, (xp, rp))
@@ -542,7 +548,7 @@ def assign_centroids(X: jax.Array, C: jax.Array):
     Xf = X.astype(jnp.float32)
     Cf = C.astype(jnp.float32)
     csq = jnp.sum(Cf * Cf, axis=-1)
-    part = csq[None, :] - 2.0 * (Xf @ Cf.T)                # (n, k)
+    part = csq[None, :] - 2.0 * jnp.matmul(Xf, Cf.T, precision=HIGHEST)
     a = jnp.argmin(part, axis=-1).astype(jnp.int32)
     d2 = jnp.min(part, axis=-1) + jnp.sum(Xf * Xf, axis=-1)
     return a, jnp.maximum(d2, 0.0)

@@ -52,14 +52,13 @@ def _fmt_table(header: List[str], rows: List[List[str]]) -> str:
 
 def kernel_table(rec: Dict[str, Any]) -> str:
     """Measured-vs-analytic roofline table from a ``kernels`` record,
-    against the peaks of the device the record was measured on."""
+    against the peaks of the device the record was measured on.  A device
+    without published peaks (a CPU run) gets its timings with "-" in the
+    roofline columns: no chip's peaks are assumed for it."""
     entries = rec["metrics"].get("kernels", [])
     if not entries:
         raise ReportError("kernels record has no metrics['kernels'] entries")
     kind = rec["env"].get("device_kind")
-    if kind not in PEAKS:
-        raise ReportError(f"kernels record measured on {kind!r}: no "
-                          f"published peaks (known: {sorted(PEAKS)})")
     rows = []
     for e in entries:
         name = e["kernel"]
@@ -67,19 +66,22 @@ def kernel_table(rec: Dict[str, Any]) -> str:
         if inv is None:
             raise ReportError(
                 f"measured kernel {name!r} has no KERNEL_INVENTORY entry")
-        shape = e["shape"]
-        flops = inv["flops"](*shape.values())
-        hbm = inv["hbm_bytes"](*shape.values())
-        terms = roofline_terms(flops, hbm, 0.0, device_kind=kind)
-        bound_us = max(terms["compute_s"], terms["memory_s"]) * 1e6
         meas_us = float(e["us"])
-        frac = bound_us / meas_us if meas_us > 0 else 0.0
-        dims = ",".join(f"{k}={v}" for k, v in shape.items())
+        bound, frac, bottleneck = "-", "-", "-"
+        if kind in PEAKS:
+            shape = e["shape"]
+            flops = inv["flops"](*shape.values())
+            hbm = inv["hbm_bytes"](*shape.values())
+            terms = roofline_terms(flops, hbm, 0.0, device_kind=kind)
+            bound_us = max(terms["compute_s"], terms["memory_s"]) * 1e6
+            bound, bottleneck = f"{bound_us:.2f}", terms["bottleneck"]
+            frac = f"{bound_us / meas_us if meas_us > 0 else 0.0:.4f}"
+        dims = ",".join(f"{k}={v}" for k, v in e["shape"].items())
         tile = str(e["tile"]) if "tile" in e else "-"
         roww = (f"{float(e['us_rowwise']) / meas_us:.2f}x"
                 if e.get("us_rowwise") and meas_us > 0 else "-")
-        rows.append([name, dims, f"{meas_us:.1f}", f"{bound_us:.2f}",
-                     terms["bottleneck"], f"{frac:.4f}", tile, roww])
+        rows.append([name, dims, f"{meas_us:.1f}", bound, bottleneck, frac,
+                     tile, roww])
     return _fmt_table(
         ["kernel", "shape", "measured_us", "roofline_us", "bound",
          "achieved_frac", "tile", "rowwise_x"], rows)
@@ -105,7 +107,11 @@ def phase_table(rec: Dict[str, Any]) -> str:
 def render(recs: Dict[str, Dict[str, Any]]) -> str:
     out = []
     if "kernels" in recs:
-        out.append("== kernel roofline (measured vs analytic) ==")
+        kind = recs["kernels"]["env"].get("device_kind")
+        out.append(f"== kernel roofline (measured vs analytic, {kind}) ==")
+        if kind not in PEAKS:
+            out.append(f"(no published peaks for {kind!r}: roofline not "
+                       f"computed)")
         out.append(kernel_table(recs["kernels"]))
         out.append("")
     for name, rec in sorted(recs.items()):

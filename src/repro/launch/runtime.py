@@ -9,10 +9,9 @@ computation.
   at a fixed ``.jax_cache/`` in the checkout root (listed in .gitignore):
   the path is part of each entry's key, so a temporary, pid- or time-named
   directory would never hit.
-* Float32 matmuls.  Every statistic of this system is float32 (the ΔI move
-  scores cancel heavily), and XLA:TPU runs an f32 matmul as one bf16 pass
-  by default.  ``init`` raises the default to full f32 precision, which is
-  what the CPU reference path computes.
+
+Numerics are not set here: the library passes float32 matmul precision at
+each of its matmuls (``kernels.ref.HIGHEST``), whatever the entry point.
 """
 from __future__ import annotations
 
@@ -25,7 +24,6 @@ CACHE_DIR = os.path.abspath(
 
 
 def init() -> None:
-    """Place the compile cache and pin f32 matmul precision."""
+    """Place the compile cache."""
     if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
         jax.config.update("jax_compilation_cache_dir", CACHE_DIR)
-    jax.config.update("jax_default_matmul_precision", "highest")

@@ -275,8 +275,8 @@ from jax import shard_map
 
 from repro.analysis.contracts import audit_trace
 
-mesh = jax.make_mesh((2,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(2)
 prog = jax.jit(shard_map(lambda x: jax.lax.psum(x, "data"), mesh=mesh,
                          in_specs=P("data"), out_specs=P()))
 low = prog.lower(jnp.zeros((8,), jnp.float32))

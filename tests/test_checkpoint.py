@@ -115,8 +115,8 @@ def test_elastic_restore_different_device_count(tmp_path, key):
         "from repro.train import checkpoint as ckpt\n"
         f"restored, step, _ = ckpt.restore({str(tmp_path)!r}, "
         "{'w': jnp.zeros((16, 8))})\n"
-        "mesh = jax.make_mesh((4,), ('data',), "
-        "axis_types=(jax.sharding.AxisType.Auto,))\n"
+        "from repro.launch.mesh import data_mesh\n"
+        "mesh = data_mesh(4)\n"
         "arr = jax.device_put(restored['w'], "
         "NamedSharding(mesh, P('data', None)))\n"
         "assert len(arr.sharding.device_set) == 4\n"

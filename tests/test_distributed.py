@@ -30,8 +30,8 @@ X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=3, key=key)
 a0 = two_means_tree(X, k, key)
 st = init_state(X, a0, k)
-mesh = jax.make_mesh((8,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(8)
 epoch = make_sharded_epoch(mesh, batch_size=128)
 dist_fn = sharded_distortion(mesh)
 assign, D, cnt = st.assign, st.D, st.cnt
@@ -82,8 +82,8 @@ for t in range(6):
                       cfg)
 ref = float(distortion(X, st.assign, k))
 
-mesh = jax.make_mesh((8,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(8)
 epoch = make_sharded_epoch(mesh, batch_size=128)
 assign, D, cnt = a0, *init_state(X, a0, k)[1:3]
 for t in range(6):
@@ -120,8 +120,8 @@ X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=2, key=key)
 G = jnp.maximum(g.ids, 0)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 source = engine.graph_source(G)
 
 for mode in ("bkm", "lloyd"):
@@ -170,8 +170,8 @@ key = jax.random.PRNGKey(0)
 n, d, k = 2048, 16, 32
 X = gmm_blobs(key, n, d, 32)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((4,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(4)
 Gdummy = jnp.zeros((n, 1), jnp.int32)
 d0 = float(distortion(X, a0, k))
 for kind in ("dense", "probe"):
@@ -217,8 +217,8 @@ X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=2, key=key)
 G = jnp.maximum(g.ids, 0)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 iters = 5
 cfg = engine.EngineConfig(batch_size=128, sparse_updates=True, iters=iters,
                           min_move_frac=-1.0, telemetry=True)
@@ -303,8 +303,8 @@ key = jax.random.PRNGKey(0)
 n, d, R = 2048, 16, 4
 assert len(jax.devices()) == R
 X = gmm_blobs(key, n, d, 32)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 
 # Alg. 3 partition source: bit-exact parity, one host sync per build
 cfg = GraphBuildConfig(kappa=8, xi=32, tau=3, chunk=256, shards=R)
@@ -388,8 +388,8 @@ X = gmm_blobs(key, n, d, 24)
 C = gmm_blobs(jax.random.fold_in(key, 1), k, d, 24)
 a, _ = ref.assign_centroids(X, C)
 index = ivf.build_ivf(X, FakeResult(a, C, k), block_rows=bl)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 sivf = ShardedIvf(mesh, index)
 nq = 32
 Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (nq, d))
@@ -481,8 +481,8 @@ X = gmm_blobs(key, n, d, 24)
 C = gmm_blobs(jax.random.fold_in(key, 1), k, d, 24)
 a, _ = ref.assign_centroids(X, C)
 index = ivf.build_ivf(X, FakeResult(a, C, k), block_rows=bl)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 sivf = ShardedIvf(mesh, index)
 nq = 32
 Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (nq, d))
@@ -551,8 +551,8 @@ X = gmm_blobs(key, n, d, 24)
 C = gmm_blobs(jax.random.fold_in(key, 1), k, d, 24)
 a, _ = ref.assign_centroids(X, C)
 base = ivf.build_ivf(X, FakeResult(a, C, k), block_rows=bl)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 nq = 32
 Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 2), (nq, d))
 
@@ -671,8 +671,8 @@ n, d, k, R = 2048, 16, 16, 4
 assert len(jax.devices()) == R
 X = gmm_blobs(key, n, d, 32)
 row_ids = jnp.arange(n, dtype=jnp.int32)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 kt = jax.random.fold_in(key, 7)
 
 def body(Xl, rl):
@@ -736,8 +736,8 @@ n2 = -(-n // k) * k
 a0 = two_means_tree(jnp.concatenate([X, X[: n2 - n]]), k, key)[:n]
 st0 = init_state(X, a0, k)
 g = jax.random.randint(key, (n, 8), 0, n, dtype=jnp.int32)
-mesh = jax.make_mesh((R,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(R)
 iters = 3
 n_pad = -(-n // R) * R
 valid = jnp.arange(n_pad) < n

@@ -112,6 +112,36 @@ def test_ivf_scan_short_candidates(key):
         assert np.all(np.isfinite(d_n[r, : sizes[r]]))
 
 
+@pytest.mark.parametrize("scan", ["f32", "int8", "pq"])
+def test_ivf_scan_query_tiles_bitwise(key, scan):
+    """The kernels' query tiling (``bq``, one kernel call per tile of the
+    batch, ragged tail padded) is bitwise-neutral: every query is scanned
+    independently."""
+    X, index = small_index(key, n=512, d=16, k=8, block_rows=16)
+    nq = 21                                   # 3 tiles of 8, ragged tail
+    Q = X[:nq] + 0.1 * jax.random.normal(jax.random.fold_in(key, 13),
+                                         (nq, X.shape[1]))
+    if scan == "f32":
+        cids, _ = ref.probe_centroids(Q, index.centroids, 3)
+        tm = ivf.build_tile_map(cids, index.starts, index.caps,
+                                max_tiles=index.max_list_tiles,
+                                block_rows=index.block_rows,
+                                null_tile=index.null_tile)
+        run = lambda bq: iv.ivf_scan(Q, index.vecs, index.ids, tm,
+                                     block_rows=index.block_rows, topk=10,
+                                     interpret=True, bq=bq)
+    else:
+        index = ivf.quantize_index(index, scan, nsub=4,
+                                   key=jax.random.fold_in(key, 24))
+        lut, qc, tm = _adc_inputs(index, Q, 3)
+        run = lambda bq: adc.ivf_scan_adc(lut, qc, index.vnorm, index.codes,
+                                          index.ids, tm,
+                                          block_rows=index.block_rows,
+                                          topk=10, interpret=True, bq=bq)
+    for whole, tiled in zip(run(0), run(8)):
+        np.testing.assert_array_equal(np.asarray(tiled), np.asarray(whole))
+
+
 # ---------------------------------------------------------------------------
 # query-grouped scan layout: kernel bitwise-exactness and search parity
 # ---------------------------------------------------------------------------

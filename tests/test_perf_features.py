@@ -57,8 +57,8 @@ n, d, k = 4096, 16, 32
 X = gmm_blobs(key, n, d, 32)
 g = build_knn_graph(X, 8, xi=32, tau=3, key=key)
 a0 = two_means_tree(X, k, key)
-mesh = jax.make_mesh((8,), ("data",),
-                     axis_types=(jax.sharding.AxisType.Auto,))
+from repro.launch.mesh import data_mesh
+mesh = data_mesh(8)
 G = jnp.maximum(g.ids, 0)
 res = {}
 for mode in (False, True):

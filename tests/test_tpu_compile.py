@@ -5,7 +5,9 @@ their block tiling, VMEM use and lowering.  These tests compile each kernel
 for one chip of a described (not attached) ``v5e:2x2`` topology, at the
 paper's SIFT width d = 128 and at the GIST width d = 960, with a real batch
 (B = 1024 rows, C = 50 candidates), and assert that the compiled program
-holds the Mosaic kernel (``tpu_custom_call``).
+holds the Mosaic kernel (``tpu_custom_call``).  The IVF scans are also
+compiled for a large serving batch (NQ_LARGE queries), which they must
+split into query tiles that fit VMEM.
 
 The TPU library may be loaded by one process at a time, so the topology is
 described inside a fixture, never while the module is imported or
@@ -28,6 +30,7 @@ from repro.kernels import refine_merge as rm
 B, C, KAPPA = 1024, 50, 50          # engine batch, candidates, list width
 K = 16384                           # clusters (10,000 rounded up to 2^14)
 NQ, BLOCK_ROWS, T = 256, 128, 24    # query batch, list tile, probed tiles
+NQ_LARGE = 4096                     # serving batch beyond one VMEM tile
 N_PAD = BLOCK_ROWS * 4096           # packed index rows
 
 
@@ -60,8 +63,9 @@ def no_persistent_cache():
     cc.reset_cache()
 
 
-def _cases(d):
-    """kernel -> (fn, [(shape, dtype), ...]) at feature width d."""
+def _cases(d, NQ=NQ):
+    """kernel -> (fn, [(shape, dtype), ...]) at feature width d and query
+    batch NQ."""
     f32, i32, u8 = jnp.float32, jnp.int32, jnp.uint8
     cr = 2 * 64 + 8                 # refine candidates: cap_factor*xi + spill
     return {
@@ -107,12 +111,23 @@ def _cases(d):
 
 
 KERNELS = sorted(_cases(128))
+SCANS = ["ivf_scan", "ivf_scan_adc_int8", "ivf_scan_adc_pq"]
 
 
 @pytest.mark.parametrize("d", [128, 960])
 @pytest.mark.parametrize("kernel", KERNELS)
 def test_kernel_compiles_for_v5e(kernel, d, one_chip, no_persistent_cache):
-    fn, args = _cases(d)[kernel]
+    _compile(_cases(d)[kernel], one_chip)
+
+
+@pytest.mark.parametrize("kernel", SCANS)
+def test_large_query_batch_compiles_for_v5e(kernel, one_chip,
+                                            no_persistent_cache):
+    _compile(_cases(960, NQ=NQ_LARGE)[kernel], one_chip)
+
+
+def _compile(case, one_chip):
+    fn, args = case
     shapes = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
               for s, dt in args]
     compiled = jax.jit(fn).lower(*shapes).compile()
