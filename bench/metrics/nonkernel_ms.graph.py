@@ -1,0 +1,6 @@
+"""Device ms per graph-build round spent outside every ``repro.kernels`` scope."""
+from bench.layer import nonkernel_ms
+
+
+def read(ctx):
+    return nonkernel_ms(ctx, "rounds")
