@@ -25,8 +25,7 @@ X = gmm_blobs(key, args.n, args.d, args.k)
 # loop runs device-resident (engine.run): one host sync for all `iters`.
 res = gk_means(X, k=args.k, kappa=16, xi=64, tau=5, iters=10, key=key)
 print(f"GK-means: distortion={res.distortion:.4f} "
-      f"(graph {res.seconds['graph']:.1f}s, init {res.seconds['init']:.1f}s, "
-      f"iters {res.seconds['iter']:.1f}s)")
+      f"({res.seconds['total']:.1f}s)")
 assert res.history[-1] <= res.history[0], "distortion must not increase"
 
 # compare against classical Lloyd k-means(++)

@@ -44,6 +44,7 @@ from repro.core import permute
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
 from repro.obs import telemetry as obs_tel
+from repro.obs.timing import layer_scope
 
 
 class BKMState(NamedTuple):
@@ -143,20 +144,18 @@ def _candidates(source: CandidateSource, xb, u, idx, lookup, D, cnt, force):
 
 def _score_gathered(xb, u, cand, D, cnt, mode, eps, force):
     """Best move per sample among gathered candidates -> (moved, want_v)."""
-    is_self = cand == u[:, None]
-    if mode == "bkm":
-        score = kops.gather_score(xb, u, cand, D, cnt, mode="bkm",
-                                  force=force)
-        score = jnp.where(is_self, -jnp.inf, score)
-        best = jnp.argmax(score, axis=1)
-        gain = jnp.take_along_axis(score, best[:, None], 1)[:, 0]
-        moved = gain > eps
-    else:
-        d2 = kops.gather_score(xb, u, cand, D, cnt, mode="lloyd",
-                               force=force)
-        best = jnp.argmin(d2, axis=1)
-        moved = ~jnp.take_along_axis(is_self, best[:, None], 1)[:, 0]
-    want_v = jnp.take_along_axis(cand, best[:, None], 1)[:, 0]
+    score = kops.gather_score(xb, u, cand, D, cnt, mode=mode, force=force)
+    with layer_scope("engine", "move"):
+        is_self = cand == u[:, None]
+        if mode == "bkm":
+            score = jnp.where(is_self, -jnp.inf, score)
+            best = jnp.argmax(score, axis=1)
+            gain = jnp.take_along_axis(score, best[:, None], 1)[:, 0]
+            moved = gain > eps
+        else:
+            best = jnp.argmin(score, axis=1)
+            moved = ~jnp.take_along_axis(is_self, best[:, None], 1)[:, 0]
+        want_v = jnp.take_along_axis(cand, best[:, None], 1)[:, 0]
     return moved, want_v
 
 
@@ -468,7 +467,8 @@ def _score_sharded(xb, u, idx, lookup, D_loc, cnt, source, cfg, comm, coff):
 
 def _score_local(xb, u, idx, lookup, D, cnt, source, cfg):
     """Scoring with the full (k, d) D on one device (incl. R-way emulation)."""
-    cand = _candidates(source, xb, u, idx, lookup, D, cnt, cfg.force)
+    with layer_scope("engine", "candidates"):
+        cand = _candidates(source, xb, u, idx, lookup, D, cnt, cfg.force)
     if cand is None:
         return _score_dense(xb, u, D, cnt, cfg.mode, cfg.eps)
     if cfg.shards > 1 and source.kind == "graph":
@@ -498,8 +498,9 @@ def _move_step(X, assign, D, cnt, moves, idx, lookup, source, cfg, comm,
     masks padded rows (rows >= n) out of proposals, stats and telemetry.
     """
     k = cnt.shape[0]
-    xb = X[idx].astype(jnp.float32)
-    u = assign[idx]
+    with layer_scope("engine", "candidates"):
+        xb = X[idx].astype(jnp.float32)
+        u = assign[idx]
 
     if comm is not None:
         moved, want_v = _score_sharded(xb, u, idx, lookup, D, cnt, source,
@@ -526,105 +527,106 @@ def _move_step(X, assign, D, cnt, moves, idx, lookup, source, cfg, comm,
         moved, want_v = _score_local(xb, u, idx, lookup, D, cnt, source,
                                      cfg)
 
-    if valid is not None:
-        moved = moved & valid[idx]
+    with layer_scope("engine", "move"):
+        if valid is not None:
+            moved = moved & valid[idx]
 
-    # proposed moves BEFORE the leaver guard (telemetry: the guard's vetoes
-    # are `proposed - moves`); None when disabled so it compiles away.
-    prop = jnp.sum(moved, dtype=jnp.int32) if cfg.telemetry else None
+        # proposed moves BEFORE the leaver guard (telemetry: the guard's vetoes
+        # are `proposed - moves`); None when disabled so it compiles away.
+        prop = jnp.sum(moved, dtype=jnp.int32) if cfg.telemetry else None
 
-    if comm is not None and cfg.sparse_updates:
-        # gather every replica's proposed moves, then apply the leaver guard
-        # + scatter locally — identical on all replicas, O(R*B*d) wire bytes
-        # instead of the dense O(k*d) psum (§Perf).
-        gx = xb * moved.astype(jnp.float32)[:, None]
-        if cfg.payload_bf16:
-            # §Perf C3: halve move-payload wire bytes.  The bitcast to u16
-            # keeps XLA's algebraic simplifier from hoisting the f32 convert
-            # back across the all-gather.
-            gx = jax.lax.bitcast_convert_type(
-                gx.astype(jnp.bfloat16), jnp.uint16)
-        gu, gv = u, jnp.where(moved, want_v, u)
-        gx = _all_gather(gx, comm)
-        gu = _all_gather(gu, comm)
-        gv = _all_gather(gv, comm)
-        if cfg.payload_bf16:
-            gx = jax.lax.bitcast_convert_type(gx, jnp.bfloat16)
-        gx = gx.astype(jnp.float32)
-        gw = (gu != gv).astype(jnp.float32)
-        leav = jax.ops.segment_sum(gw, gu, num_segments=k)
-        ok = (cnt - leav) >= 1.0
-        gv = jnp.where(ok[gu], gv, gu)                   # veto unsafe moves
-        gx = gx * (gu != gv).astype(jnp.float32)[:, None]
-        gw2 = (gu != gv).astype(jnp.float32)
-        # scatter only the rows this shard owns into its D block; cnt is
-        # replicated, so its pair of (k,) scatters runs identically
-        # everywhere.  Same adds in the same gathered-row order as the
-        # emulation's fused full-D scatter, hence bitwise-equal blocks.
-        # Non-owned rows route to the out-of-range sentinel k_loc (negative
-        # indices would WRAP before the drop-mode bounds check).
-        k_loc = D.shape[0]
-        iu, iv = gu - coff, gv - coff
-        iu = jnp.where((iu >= 0) & (iu < k_loc), iu, k_loc)
-        iv = jnp.where((iv >= 0) & (iv < k_loc), iv, k_loc)
-        D = D.at[iu].add(-gx, mode="drop").at[iv].add(gx, mode="drop")
-        cnt = cnt.at[gu].add(-gw2).at[gv].add(gw2)
-        moved = moved & ok[u]
-        v = jnp.where(moved, want_v, u)
-    elif comm is not None:
-        # dense statistics sync: global leaver guard + delta psum in the
-        # transposed (d, k) layout — same adds in the same order as the
-        # (k, d) scatter (bitwise-equal transposed), but the replicated
-        # all-reduce operand leads with d, which the audit does not track
-        leav = jax.ops.segment_sum(moved.astype(jnp.float32), u,
-                                   num_segments=k)
-        leav = _psum(leav, comm)
-        moved = moved & ((cnt - leav) >= 1.0)[u]
-        v = jnp.where(moved, want_v, u)
-        w = moved.astype(jnp.float32)[:, None]
-        k_loc = D.shape[0]
-        gxT = (xb * w).T                                 # (d, B)
-        dD_T = (jnp.zeros((D.shape[1], k), jnp.float32)
-                .at[:, u].add(-gxT).at[:, v].add(gxT))
-        dD_T = _psum(dD_T, comm)
-        dc = jnp.zeros_like(cnt).at[u].add(-w[:, 0]).at[v].add(w[:, 0])
-        D = D + jax.lax.dynamic_slice(dD_T, (0, coff),
-                                      (D.shape[1], k_loc)).T
-        cnt = cnt + _psum(dc, comm)
-    else:
-        # single device.  The guard blocks all leavers of any cluster whose
-        # leaver count would reach its population (conservative, rare).
-        leav = jax.ops.segment_sum(moved.astype(jnp.float32), u,
-                                   num_segments=k)
-        moved = moved & ((cnt - leav) >= 1.0)[u]
-        v = jnp.where(moved, want_v, u)
-        gx = xb * moved.astype(jnp.float32)[:, None]
-        if cfg.payload_bf16 and cfg.sparse_updates:
-            gx = gx.astype(jnp.bfloat16).astype(jnp.float32)
-        if cfg.shards > 1 and not cfg.sparse_updates:
-            # mirror the dense-psum arithmetic: per-shard partial deltas,
-            # then a sequential device-order sum (matches the all-reduce up
-            # to its backend-defined fp ordering — assignments and counts
-            # stay exact, D to ~1 ulp; the parity test pins all three)
-            R = cfg.shards
-            bs = idx.shape[0] // R
-            dD_tot, dc_tot = None, None
-            for s in range(R):
-                sl = slice(s * bs, (s + 1) * bs)
-                us, vs, gs = u[sl], v[sl], gx[sl]
-                ms = (us != vs).astype(jnp.float32)
-                dDs = jnp.zeros_like(D).at[us].add(-gs).at[vs].add(gs)
-                dcs = jnp.zeros_like(cnt).at[us].add(-ms).at[vs].add(ms)
-                dD_tot = dDs if s == 0 else dD_tot + dDs
-                dc_tot = dcs if s == 0 else dc_tot + dcs
-            D = D + dD_tot
-            cnt = cnt + dc_tot
+        if comm is not None and cfg.sparse_updates:
+            # gather every replica's proposed moves, then apply the leaver
+            # guard + scatter locally — identical on all replicas, O(R*B*d)
+            # wire bytes instead of the dense O(k*d) psum (§Perf).
+            gx = xb * moved.astype(jnp.float32)[:, None]
+            if cfg.payload_bf16:
+                # §Perf C3: halve move-payload wire bytes.  The bitcast to
+                # u16 keeps XLA's algebraic simplifier from hoisting the f32
+                # convert back across the all-gather.
+                gx = jax.lax.bitcast_convert_type(
+                    gx.astype(jnp.bfloat16), jnp.uint16)
+            gu, gv = u, jnp.where(moved, want_v, u)
+            gx = _all_gather(gx, comm)
+            gu = _all_gather(gu, comm)
+            gv = _all_gather(gv, comm)
+            if cfg.payload_bf16:
+                gx = jax.lax.bitcast_convert_type(gx, jnp.bfloat16)
+            gx = gx.astype(jnp.float32)
+            gw = (gu != gv).astype(jnp.float32)
+            leav = jax.ops.segment_sum(gw, gu, num_segments=k)
+            ok = (cnt - leav) >= 1.0
+            gv = jnp.where(ok[gu], gv, gu)               # veto unsafe moves
+            gx = gx * (gu != gv).astype(jnp.float32)[:, None]
+            gw2 = (gu != gv).astype(jnp.float32)
+            # scatter only the rows this shard owns into its D block; cnt is
+            # replicated, so its pair of (k,) scatters runs identically
+            # everywhere.  Same adds in the same gathered-row order as the
+            # emulation's fused full-D scatter, hence bitwise-equal blocks.
+            # Non-owned rows route to the out-of-range sentinel k_loc (negative
+            # indices would WRAP before the drop-mode bounds check).
+            k_loc = D.shape[0]
+            iu, iv = gu - coff, gv - coff
+            iu = jnp.where((iu >= 0) & (iu < k_loc), iu, k_loc)
+            iv = jnp.where((iv >= 0) & (iv < k_loc), iv, k_loc)
+            D = D.at[iu].add(-gx, mode="drop").at[iv].add(gx, mode="drop")
+            cnt = cnt.at[gu].add(-gw2).at[gv].add(gw2)
+            moved = moved & ok[u]
+            v = jnp.where(moved, want_v, u)
+        elif comm is not None:
+            # dense statistics sync: global leaver guard + delta psum in the
+            # transposed (d, k) layout — same adds in the same order as the
+            # (k, d) scatter (bitwise-equal transposed), but the replicated
+            # all-reduce operand leads with d, which the audit does not track
+            leav = jax.ops.segment_sum(moved.astype(jnp.float32), u,
+                                       num_segments=k)
+            leav = _psum(leav, comm)
+            moved = moved & ((cnt - leav) >= 1.0)[u]
+            v = jnp.where(moved, want_v, u)
+            w = moved.astype(jnp.float32)[:, None]
+            k_loc = D.shape[0]
+            gxT = (xb * w).T                                 # (d, B)
+            dD_T = (jnp.zeros((D.shape[1], k), jnp.float32)
+                    .at[:, u].add(-gxT).at[:, v].add(gxT))
+            dD_T = _psum(dD_T, comm)
+            dc = jnp.zeros_like(cnt).at[u].add(-w[:, 0]).at[v].add(w[:, 0])
+            D = D + jax.lax.dynamic_slice(dD_T, (0, coff),
+                                          (D.shape[1], k_loc)).T
+            cnt = cnt + _psum(dc, comm)
         else:
-            gw = (u != v).astype(jnp.float32)
-            D, cnt = _scatter_moves(D, cnt, u, v, gx, gw)
+            # single device.  The guard blocks all leavers of any cluster whose
+            # leaver count would reach its population (conservative, rare).
+            leav = jax.ops.segment_sum(moved.astype(jnp.float32), u,
+                                       num_segments=k)
+            moved = moved & ((cnt - leav) >= 1.0)[u]
+            v = jnp.where(moved, want_v, u)
+            gx = xb * moved.astype(jnp.float32)[:, None]
+            if cfg.payload_bf16 and cfg.sparse_updates:
+                gx = gx.astype(jnp.bfloat16).astype(jnp.float32)
+            if cfg.shards > 1 and not cfg.sparse_updates:
+                # mirror the dense-psum arithmetic: per-shard partial deltas,
+                # then a sequential device-order sum (matches the all-reduce up
+                # to its backend-defined fp ordering — assignments and counts
+                # stay exact, D to ~1 ulp; the parity test pins all three)
+                R = cfg.shards
+                bs = idx.shape[0] // R
+                dD_tot, dc_tot = None, None
+                for s in range(R):
+                    sl = slice(s * bs, (s + 1) * bs)
+                    us, vs, gs = u[sl], v[sl], gx[sl]
+                    ms = (us != vs).astype(jnp.float32)
+                    dDs = jnp.zeros_like(D).at[us].add(-gs).at[vs].add(gs)
+                    dcs = jnp.zeros_like(cnt).at[us].add(-ms).at[vs].add(ms)
+                    dD_tot = dDs if s == 0 else dD_tot + dDs
+                    dc_tot = dcs if s == 0 else dc_tot + dcs
+                D = D + dD_tot
+                cnt = cnt + dc_tot
+            else:
+                gw = (u != v).astype(jnp.float32)
+                D, cnt = _scatter_moves(D, cnt, u, v, gx, gw)
 
-    assign = assign.at[idx].set(v.astype(jnp.int32))
-    moves = moves + jnp.sum(moved, dtype=jnp.int32)
+        assign = assign.at[idx].set(v.astype(jnp.int32))
+        moves = moves + jnp.sum(moved, dtype=jnp.int32)
     return assign, D, cnt, moves, prop
 
 

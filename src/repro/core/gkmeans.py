@@ -35,6 +35,8 @@ class GKMeansResult:
     history: List[float]       # per-epoch distortion
     moves: List[int]           # per-epoch accepted moves
     graph: Optional[KnnGraph]
+    # {"total": s}: host seconds from entry until the results were ready;
+    # stage device times come from the named scopes in a profile
     seconds: dict = field(default_factory=dict)
     # per-round Alg. 3 build observability (None when a graph was passed in)
     graph_diag: Optional[BuildDiagnostics] = None
@@ -84,24 +86,18 @@ def gk_means(
     _, k2 = pad_plan(n, k)
     kg, ki, kb = jax.random.split(key, 3)
 
-    sec = {}
     gdiag = None
-    with span("graph", out=sec):
+    # graph build, init and engine run are dispatched back to back with no
+    # host sync in between; the span closes on the run's ONE host sync, so
+    # its seconds are the whole job's
+    with span("repro.gk_means") as sp:
         if graph is None:
             graph, gdiag = build_knn_graph(X, kappa, xi=xi, tau=tau, key=kg,
                                            guided=guided_graph,
                                            return_diagnostics=True)
-
-    # graph build, init and engine run are dispatched back-to-back with no
-    # host sync in between (no span sets .result, so none blocks): "graph"
-    # and "init" measure compile + dispatch, and the device time of all
-    # three lands in "iter" (the single device_get below).
-    with span("init", out=sec):
         state = engine.init_state(X, _tree_init(X, k2, ki), k2)
         dist0_d = engine.stats_distortion(
             jnp.sum(jnp.square(X.astype(jnp.float32))), state.D, state.cnt, n)
-
-    with span("iter", out=sec):
         source = engine.graph_source(graph.ids)
         cfg = engine.EngineConfig(batch_size=min(batch_size, n), mode=mode,
                                   iters=iters, min_move_frac=min_move_frac,
@@ -112,12 +108,13 @@ def gk_means(
 
         # the run's ONE host sync: everything below is numpy (the telemetry
         # rides the same sync — it was accumulated inside the run's
-        # while_loop)
+        # while_loop); a block on ``sp.result`` first would be a second
         state, hist, moves, epochs, final, C, tel, dist0 = jax.device_get(
             (state, hist_d, moves_d, epochs_d, final_d, C, tel_d, dist0_d))
 
     epochs = int(epochs)
     history = [float(h) for h in hist[:epochs]]
     return GKMeansResult(state.assign, C, k2, float(final), history,
-                         [int(m) for m in moves[:epochs]], graph, sec,
+                         [int(m) for m in moves[:epochs]], graph,
+                         {"total": sp.seconds},
                          gdiag, tel, float(dist0))

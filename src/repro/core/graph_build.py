@@ -62,6 +62,7 @@ from repro.core.two_means import _TreeTopo, two_means_dist
 from repro.kernels import ops as kops
 from repro.kernels.ref import HIGHEST
 from repro.obs import telemetry as obs_tel
+from repro.obs.timing import layer_scope
 
 
 # beyond this list width the sort-based merge_topk beats the fused kernel's
@@ -155,38 +156,41 @@ def _refine_rows(x_own, rows, cand_ids, g_ids, g_d, Xsrc, chunk, force):
     chunk = max(1, min(chunk, B))
     nb = -(-B // chunk)
     Bp = nb * chunk
-    if Bp != B:
-        # pad to a chunk multiple with clamped copies; extras are discarded
-        idx = jnp.minimum(jnp.arange(Bp, dtype=jnp.int32), B - 1)
-        x_own, rows, cand_ids, g_ids, g_d = (
-            x_own[idx], rows[idx], cand_ids[idx], g_ids[idx], g_d[idx])
+    with layer_scope("graph", "candidates"):
+        if Bp != B:
+            # pad to a chunk multiple with clamped copies; extras are discarded
+            idx = jnp.minimum(jnp.arange(Bp, dtype=jnp.int32), B - 1)
+            x_own, rows, cand_ids, g_ids, g_d = (
+                x_own[idx], rows[idx], cand_ids[idx], g_ids[idx], g_d[idx])
 
-    if kappa > _WIDE_KAPPA:
-        # wide lists (e.g. closure's trees*(leaf-1)): the fused kernel's
-        # unrolled selection merge is O(κ(κ+C)) per row — the three-argsort
-        # merge_topk wins past ~64; distances stay per-row exact, so the
-        # single<->sharded bitwise parity is chunk-invariant as before
-        def body(args):
-            xo, rw, ci, gi, gd = args
-            Y = Xsrc[rw].astype(jnp.float32)
-            cd = jnp.sum((Y - xo.astype(jnp.float32)[:, None, :]) ** 2, -1)
-            cd = jnp.where(ci < 0, jnp.inf, cd)
-            return merge_topk(gi, gd, ci, cd, kappa)
-    else:
-        def body(args):
-            xo, rw, ci, gi, gd = args
-            return kops.refine_merge(xo, rw, ci, gi, gd, Xsrc, force=force)
+        if kappa > _WIDE_KAPPA:
+            # wide lists (e.g. closure's trees*(leaf-1)): the fused kernel's
+            # unrolled selection merge is O(κ(κ+C)) per row — the
+            # three-argsort merge_topk wins past ~64; distances stay per-row
+            # exact, so the single<->sharded bitwise parity is
+            # chunk-invariant as before
+            def body(args):
+                xo, rw, ci, gi, gd = args
+                Y = Xsrc[rw].astype(jnp.float32)
+                cd = jnp.sum((Y - xo.astype(jnp.float32)[:, None, :]) ** 2, -1)
+                cd = jnp.where(ci < 0, jnp.inf, cd)
+                return merge_topk(gi, gd, ci, cd, kappa)
+        else:
+            def body(args):
+                xo, rw, ci, gi, gd = args
+                return kops.refine_merge(xo, rw, ci, gi, gd, Xsrc, force=force)
 
-    if nb > 1:
-        C = rows.shape[1]
-        ids, d = jax.lax.map(body, (
-            x_own.reshape(nb, chunk, -1), rows.reshape(nb, chunk, C),
-            cand_ids.reshape(nb, chunk, C), g_ids.reshape(nb, chunk, kappa),
-            g_d.reshape(nb, chunk, kappa)))
-        ids, d = ids.reshape(Bp, kappa), d.reshape(Bp, kappa)
-    else:
-        ids, d = body((x_own, rows, cand_ids, g_ids, g_d))
-    return ids[:B], d[:B]
+        if nb > 1:
+            C = rows.shape[1]
+            ids, d = jax.lax.map(body, (
+                x_own.reshape(nb, chunk, -1), rows.reshape(nb, chunk, C),
+                cand_ids.reshape(nb, chunk, C),
+                g_ids.reshape(nb, chunk, kappa),
+                g_d.reshape(nb, chunk, kappa)))
+            ids, d = ids.reshape(Bp, kappa), d.reshape(Bp, kappa)
+        else:
+            ids, d = body((x_own, rows, cand_ids, g_ids, g_d))
+        return ids[:B], d[:B]
 
 
 def _guided_stats(X, assign, k0, topo: _TreeTopo):
@@ -259,30 +263,33 @@ def _partition_round(X_full, X_loc, row_ids, real_id, own_real, g_ids, g_d,
                                      lambda a: (a, moves), assign)
     cap = cfg.cap_factor * cfg.xi
     spill = cfg.spill
-    if comm is not None:
-        tT, sp, ovf = members_table_local(assign, row_ids, k0, cap // R,
-                                          spill)
-        table_T = engine._all_gather(tT, comm)               # (cap, k0)
-        spill_ids = engine._all_gather(sp, comm)             # (R*spill,)
-        overflow = engine._psum(ovf, comm)
-    else:
-        bl = lambda x: x.reshape((R, -1) + x.shape[1:])
-        tT, sp, ovf = jax.vmap(
-            lambda a, p: members_table_local(a, p, k0, cap // R, spill)
-        )(bl(assign), bl(row_ids))
-        table_T = tT.reshape(cap, k0)
-        spill_ids = sp.reshape(R * spill)
-        overflow = jnp.sum(ovf, dtype=jnp.int32)
-    cand_rows = jnp.take(table_T, assign, axis=1).T          # (B, cap)
-    spill_b = jnp.broadcast_to(spill_ids[None, :],
-                               (B, spill_ids.shape[0]))
-    cand_rows = jnp.concatenate([cand_rows, spill_b], axis=1)
-    cand_ids = jnp.where(cand_rows >= 0,
-                         real_id[jnp.maximum(cand_rows, 0)], -1)
-    # mask self and phantoms of self; phantom dupes dedupe in the merge
-    cand_ids = jnp.where(cand_ids == own_real[:, None], -1, cand_ids)
-    g_ids, g_d = _refine_rows(X_loc, jnp.maximum(cand_rows, 0), cand_ids,
-                              g_ids, g_d, X_full, cfg.chunk, cfg.force)
+    with layer_scope("graph", "members"):
+        if comm is not None:
+            tT, sp, ovf = members_table_local(assign, row_ids, k0, cap // R,
+                                              spill)
+            table_T = engine._all_gather(tT, comm)               # (cap, k0)
+            spill_ids = engine._all_gather(sp, comm)             # (R*spill,)
+            overflow = engine._psum(ovf, comm)
+        else:
+            bl = lambda x: x.reshape((R, -1) + x.shape[1:])
+            tT, sp, ovf = jax.vmap(
+                lambda a, p: members_table_local(a, p, k0, cap // R, spill)
+            )(bl(assign), bl(row_ids))
+            table_T = tT.reshape(cap, k0)
+            spill_ids = sp.reshape(R * spill)
+            overflow = jnp.sum(ovf, dtype=jnp.int32)
+    with layer_scope("graph", "candidates"):
+        cand_rows = jnp.take(table_T, assign, axis=1).T          # (B, cap)
+        spill_b = jnp.broadcast_to(spill_ids[None, :],
+                                   (B, spill_ids.shape[0]))
+        cand_rows = jnp.concatenate([cand_rows, spill_b], axis=1)
+        cand_ids = jnp.where(cand_rows >= 0,
+                             real_id[jnp.maximum(cand_rows, 0)], -1)
+        # mask self and phantoms of self; phantom dupes dedupe in the merge
+        cand_ids = jnp.where(cand_ids == own_real[:, None], -1, cand_ids)
+        cand_rows = jnp.maximum(cand_rows, 0)
+    g_ids, g_d = _refine_rows(X_loc, cand_rows, cand_ids, g_ids, g_d, X_full,
+                              cfg.chunk, cfg.force)
     return g_ids, g_d, overflow, moves
 
 
@@ -334,7 +341,8 @@ def _build_rounds(X_loc, row_ids, real_id, key, *, cfg, n, k0, comm,
     g_ids = jnp.full((B, cfg.kappa), -1, jnp.int32)
     g_d = jnp.full((B, cfg.kappa), jnp.inf, jnp.float32)
     if cfg.random_init:
-        cand0 = _random_ids(kinit, real_id, n, cfg.kappa)[row_ids]
+        with layer_scope("graph", "candidates"):
+            cand0 = _random_ids(kinit, real_id, n, cfg.kappa)[row_ids]
         g_ids, g_d = _refine_rows(X_loc, jnp.maximum(cand0, 0), cand0,
                                   g_ids, g_d, X_full, cfg.chunk, cfg.force)
 

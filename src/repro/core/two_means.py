@@ -28,6 +28,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels.ref import HIGHEST
+from repro.obs.timing import layer_scope
 
 
 def _is_pow2(v: int) -> bool:
@@ -352,5 +353,7 @@ def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
         return seg * 2 + jnp.where(left, 0, 1), None
 
     seg0 = jnp.zeros(row_ids.shape, jnp.int32)
-    seg, _ = jax.lax.scan(level, seg0, jnp.arange(levels, dtype=jnp.int32))
+    with layer_scope("graph", "tree"):
+        seg, _ = jax.lax.scan(level, seg0,
+                              jnp.arange(levels, dtype=jnp.int32))
     return seg

@@ -28,6 +28,7 @@ import jax.numpy as jnp
 from repro.index.ivf import IvfIndex
 from repro.kernels import ops as kops
 from repro.kernels import ref as kref
+from repro.obs.timing import span
 
 
 @functools.partial(jax.jit, static_argnames=("max_tiles", "block_rows",
@@ -187,36 +188,57 @@ def search(index: IvfIndex, Q: jax.Array, *, topk: int = 10,
     `rerank=0` disables the tail and returns distances to the codec
     reconstructions).  With rerank on, returned d2 is exact squared L2
     again — the codec only decides WHICH candidates survive to the tail.
-    """
-    assert nprobe >= 1, nprobe
-    nprobe = min(nprobe, index.k)
-    if index.max_list_tiles == 0:         # every list empty: nothing to scan
-        return _no_candidates(Q.shape[0], topk)
-    cids, _ = kops.probe_centroids(Q, index.centroids, nprobe, force=force)
-    tm = build_tile_map(cids, index.starts, index.caps,
-                        max_tiles=index.max_list_tiles,
-                        block_rows=index.block_rows,
-                        null_tile=index.null_tile)
-    if codec != "f32":
-        assert qgroup is None, "codec scan is per-query only (no qgroup)"
-        assert index.codec is not None and index.codec.kind == codec, \
-            (codec, index.codec_kind)
-        from repro.index import quantize as _q
 
-        depth = _rerank_depth(topk, rerank)
-        lut, qc = _q.build_lut(index.codec, Q)
-        ids, pos, part = kops.ivf_scan_adc(
-            lut, qc, index.vnorm, index.codes, index.ids, tm,
-            block_rows=index.block_rows, topk=(depth or topk), force=force)
-        if not depth:
-            return _finalize(ids, part, Q)
-        rid, rpart = exact_rerank(Q, index.vecs, index.ids, pos, topk=topk)
-        return _finalize(rid, rpart, Q)
-    if qgroup is not None and qgroup > 1:
-        return _search_grouped(index, Q, tm, topk=topk, qgroup=qgroup,
-                               force=force)
-    return kops.ivf_scan(Q, index.vecs, index.ids, tm,
-                         block_rows=index.block_rows, topk=topk, force=force)
+    Each call runs under one ``repro.search`` span (``obs.span``: host
+    dispatch, no sync) that counts ``grid_rows``, the rows the scan's grid
+    streams, and ``grid_flops``, the operations it spends on them.
+    """
+    with span("repro.search") as sp:
+        assert nprobe >= 1, nprobe
+        nprobe = min(nprobe, index.k)
+        if index.max_list_tiles == 0:     # every list empty: nothing to scan
+            return _no_candidates(Q.shape[0], topk)
+        cids, _ = kops.probe_centroids(Q, index.centroids, nprobe,
+                                       force=force)
+        tm = build_tile_map(cids, index.starts, index.caps,
+                            max_tiles=index.max_list_tiles,
+                            block_rows=index.block_rows,
+                            null_tile=index.null_tile)
+        # rows the scan's grid streams: every query walks nprobe lists of
+        # the longest list's tiles (a group of qgroup queries walks their
+        # union, qgroup * nprobe lists' worth); each row scored against a
+        # query costs a multiply and an add per code column
+        q = Q.shape[0]
+        per_q = nprobe * index.max_list_tiles * index.block_rows
+        if codec != "f32":
+            assert qgroup is None, "codec scan is per-query only (no qgroup)"
+            assert index.codec is not None and index.codec.kind == codec, \
+                (codec, index.codec_kind)
+            from repro.index import quantize as _q
+
+            sp.count(grid_rows=q * per_q, grid_flops=2 * q * per_q *
+                     _q.code_width(index.codec, index.dim))
+            depth = _rerank_depth(topk, rerank)
+            lut, qc = _q.build_lut(index.codec, Q)
+            ids, pos, part = kops.ivf_scan_adc(
+                lut, qc, index.vnorm, index.codes, index.ids, tm,
+                block_rows=index.block_rows, topk=(depth or topk),
+                force=force)
+            if not depth:
+                return _finalize(ids, part, Q)
+            rid, rpart = exact_rerank(Q, index.vecs, index.ids, pos,
+                                      topk=topk)
+            return _finalize(rid, rpart, Q)
+        if qgroup is not None and qgroup > 1:
+            qg = -(-q // qgroup) * qgroup
+            sp.count(grid_rows=qg * per_q,
+                     grid_flops=2 * qg * per_q * qgroup * index.dim)
+            return _search_grouped(index, Q, tm, topk=topk, qgroup=qgroup,
+                                   force=force)
+        sp.count(grid_rows=q * per_q, grid_flops=2 * q * per_q * index.dim)
+        return kops.ivf_scan(Q, index.vecs, index.ids, tm,
+                             block_rows=index.block_rows, topk=topk,
+                             force=force)
 
 
 def merge_shard_topk(ids: jax.Array, part: jax.Array, topk: int):

@@ -7,6 +7,7 @@ The two load-bearing guarantees:
     accumulator buffers.
 """
 import json
+import re
 
 import jax
 import jax.numpy as jnp
@@ -18,6 +19,7 @@ from repro.data import gmm_blobs
 from repro.obs import (emit, run_record, sync_counter, span, validate_record,
                        write_json)
 from repro.obs import telemetry as obs_tel
+from repro.obs import timing
 
 
 @pytest.fixture(scope="module")
@@ -139,6 +141,7 @@ def test_gk_means_surfaces_telemetry(setup):
                    telemetry=True)
     assert res.telemetry is not None
     assert len(obs_tel.column(res.telemetry, "moves")) == 3
+    assert set(res.seconds) == {"total"} and res.seconds["total"] > 0
     res0 = gk_means(X, k, kappa=8, xi=32, tau=2, iters=3, key=key)
     assert res0.telemetry is None
     np.testing.assert_array_equal(np.asarray(res.assign),
@@ -164,10 +167,69 @@ def test_sync_counter_counts_gets_and_blocks():
 
 
 def test_span_times_and_files():
-    secs = {}
-    with span("mul", out=secs) as sp:
+    with span("mul") as sp:
         sp.result = jnp.ones((128, 128)) @ jnp.ones((128, 128))
-    assert sp.seconds > 0 and secs["mul"] == sp.seconds
+    rec = timing.recent("mul", 1)[-1]
+    assert sp.seconds > 0 and rec.end_ns - rec.start_ns == \
+        pytest.approx(sp.seconds * 1e9)
+
+
+class _Clock:
+    """A fake ``perf_counter_ns``: each read returns the next given time."""
+
+    def __init__(self, *ns):
+        self.ns = list(ns)
+
+    def __call__(self):
+        return self.ns.pop(0)
+
+
+def test_span_ring_records_parent_self_time_and_counts(monkeypatch):
+    timing.clear()
+    # outer 0..100, inner 10..40 and 50..60: outer's self time is 60
+    monkeypatch.setattr(timing.time, "perf_counter_ns",
+                        _Clock(0, 10, 40, 50, 60, 100))
+    with span("outer") as outer:
+        outer.count(rows=3)
+        for _ in range(2):
+            with span("inner") as inner:
+                inner.count(rows=1, tiles=2)
+                inner.count(rows=1)
+        outer.count(rows=4)
+    (o,) = timing.recent("outer", 5)
+    i1, i2 = timing.recent("inner", 5)
+    assert (o.start_ns, o.end_ns, o.parent, o.self_ns) == (0, 100, None, 60)
+    assert o.counts == {"rows": 7}
+    assert (i1.start_ns, i1.end_ns, i1.parent, i1.self_ns) == (10, 40,
+                                                               "outer", 30)
+    assert i2.start_ns == 50 and i1.counts == i2.counts == {"rows": 2,
+                                                            "tiles": 2}
+    assert outer.seconds == pytest.approx(100e-9)
+
+
+def test_span_ring_is_bounded_and_reads_the_last():
+    timing.clear()
+    for i in range(timing.RING_SIZE + 5):
+        with span("tick") as sp:
+            sp.count(i=i)
+    with span("other"):
+        pass
+    got = timing.recent("tick", 2 * timing.RING_SIZE)
+    assert len(got) == timing.RING_SIZE - 1      # "other" took a slot
+    assert [r.counts["i"] for r in timing.recent("tick", 3)] == [
+        timing.RING_SIZE + 2, timing.RING_SIZE + 3, timing.RING_SIZE + 4]
+    assert timing.recent("tick", 0) == [] and timing.recent("none", 3) == []
+
+
+def test_span_records_nothing_when_its_block_raises():
+    timing.clear()
+    with pytest.raises(RuntimeError):
+        with span("boom"):
+            raise RuntimeError("x")
+    with span("after") as sp:
+        pass
+    assert timing.recent("boom", 1) == []
+    assert timing.recent("after", 1)[0].parent is None and sp.parent is None
 
 
 def test_kernel_scope_names_land_in_hlo():
@@ -175,6 +237,95 @@ def test_kernel_scope_names_land_in_hlo():
     txt = jax.jit(ops.pairwise_sq).lower(
         jnp.ones((2, 8, 4))).compile().as_text()
     assert "repro.kernels.pairwise_sq" in txt
+
+
+# ---------------------------------------------------------------------------
+# layer scopes: where each part of the graph build and the engine epoch runs
+# ---------------------------------------------------------------------------
+
+LAYER_SCOPES = {"graph": ("repro.graph.tree", "repro.graph.members",
+                          "repro.graph.candidates"),
+                "engine": ("repro.engine.candidates", "repro.engine.move")}
+
+
+def _compiled_text(program, setup):
+    from repro.core import GraphBuildConfig
+    from repro.core.graph_build import _build_single
+    X, a0, G, k, key = setup
+    if program == "graph":
+        return _build_single.lower(
+            X, key, GraphBuildConfig(kappa=8, xi=32, tau=1)).compile().as_text()
+    return engine.run.lower(X, engine.init_state(X, a0, k),
+                            engine.graph_source(G), key,
+                            _run_cfg(False)).compile().as_text()
+
+
+@pytest.mark.parametrize("program,scope", [
+    (p, s) for p, scopes in LAYER_SCOPES.items() for s in scopes])
+def test_layer_scope_names_land_in_hlo(program, scope, setup):
+    """Each layer scope marks ops of its program, and every non-kernel
+    ``repro.*`` scope in the program is one of the layer scopes."""
+    txt = _compiled_text(program, setup)
+    assert scope in txt
+    named = set(re.findall(r"repro\.[A-Za-z0-9_]+\.[A-Za-z0-9_]+", txt))
+    assert {n for n in named if not n.startswith("repro.kernels.")} == \
+        set(LAYER_SCOPES[program])
+
+
+def test_layer_scope_refuses_the_kernel_prefix():
+    with pytest.raises(ValueError):
+        timing.layer_scope("kernels", "refine_merge")
+    assert all(not s.startswith(timing.SCOPE_PREFIX)
+               for scopes in LAYER_SCOPES.values() for s in scopes)
+
+
+# ---------------------------------------------------------------------------
+# index.search: one span a call, its grid count, and no host sync
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def small_ivf():
+    from repro import index as ivf
+    from repro.kernels import ref
+    key = jax.random.PRNGKey(3)
+    X = gmm_blobs(key, 1024, 16, 16)
+    C = gmm_blobs(jax.random.fold_in(key, 1), 16, 16, 16)
+    a, _ = ref.assign_centroids(X, C)
+
+    class Result:
+        assign, centroids, k = a, C, 16
+
+    return ivf.build_ivf(X, Result, block_rows=32), X[:40]
+
+
+@pytest.mark.parametrize("qgroup,rows_q", [(None, 40), (8, 40), (16, 48)])
+def test_search_records_one_span_with_its_grid(small_ivf, qgroup, rows_q):
+    """grid_rows = q x nprobe x max_list_tiles x block_rows, q rounded up to
+    a whole number of groups in the grouped layout."""
+    from repro import index as ivf
+    index, Q = small_ivf
+    timing.clear()
+    for _ in range(3):
+        ivf.search(index, Q, topk=5, nprobe=4, qgroup=qgroup)
+    spans = timing.recent("repro.search", 10)
+    assert len(spans) == 3 and all(s.parent is None for s in spans)
+    rows = rows_q * 4 * index.max_list_tiles * index.block_rows
+    group = qgroup or 1
+    assert spans[-1].counts == {"grid_rows": rows,
+                                "grid_flops": 2 * rows * group * 16}
+
+
+def test_warm_search_makes_no_host_sync(small_ivf):
+    """Pinned: a warm ``index.search`` dispatches without a host sync (on
+    a TPU the guard raises on any implicit device->host transfer; on the
+    CPU it cannot trip, see above, and only the tally is checked)."""
+    from repro import index as ivf
+    index, Q = small_ivf
+    jax.block_until_ready(ivf.search(index, Q, topk=5, nprobe=4))
+    with sync_counter() as sc:
+        out = ivf.search(index, Q, topk=5, nprobe=4)
+    assert sc.syncs == 0
+    jax.block_until_ready(out)
 
 
 # ---------------------------------------------------------------------------
