@@ -368,11 +368,12 @@ _IVF_BUDGET: Dict[str, int] = {"all-gather": 4}
 # claim); the guided pass — a lax.cond branch, so the parser counts its ops
 # once, matching the round-0 skip — pays the s32[n_pad] assignment + 2
 # sparse index gathers + the s32[n_pad, kappa+1] candidate ids + one
-# (R, d, k0) guided-stats fsum partial (5); the tree pays one (R, d, k0)
-# tot_T fsum per level plus one s1_T fsum per refine iteration; the member
-# table pays the (cap, k0) table + spill-list gathers per round.
+# (R, d, k0) guided-stats segment-sum partial (5); the tree pays one
+# (R, d, k0) tot_T segment-sum partial per level plus one s1_T partial per
+# refine iteration; the member table pays the (cap, k0) table + spill-list
+# gathers per round.
 # all-reduces: per level per round 1 cntc seg-psum + 4 seed pmins + 2
-# (d, k0) seed-vector psums + _REFINE * (8 radix histogram psums + 1 n1
+# (d, k0) gathered seed-row psums + _REFINE * (8 radix histogram psums + 1 n1
 # seg-psum) + 8 final-split radix psums; the guided branch pays its
 # candidate-row payload psum + k0-counts psum + moves psum (3); the member
 # table 1 overflow psum per round.  collective-permute: the 2 (chunk,

@@ -58,9 +58,8 @@ import jax.numpy as jnp
 
 from repro.core import engine
 from repro.core.knn_graph import KnnGraph, members_table_local, merge_topk
-from repro.core.two_means import _TreeTopo, two_means_dist
+from repro.core.two_means import _seg_sum_T, _TreeTopo, two_means_dist
 from repro.kernels import ops as kops
-from repro.kernels.ref import HIGHEST
 from repro.obs import telemetry as obs_tel
 from repro.obs.timing import layer_scope
 
@@ -195,15 +194,13 @@ def _refine_rows(x_own, rows, cand_ids, g_ids, g_d, Xsrc, chunk, force):
 
 def _guided_stats(X, assign, k0, topo: _TreeTopo):
     """Guided-pass cluster stats, both topologies: transposed (d, k0)
-    composite sums combined in FIXED shard order (all-gather + ordered sum
-    — bit-exact across topologies, unlike an unordered float psum) plus
-    order-invariant int counts.  Never materialises a replicated (k0, d)
-    operand in the sharded trace."""
+    composite sums — per-shard O(B * d) segment sums (``_seg_sum_T``),
+    combined in FIXED shard order (all-gather + ordered sum — bit-exact
+    across topologies, unlike an unordered float psum) — plus
+    order-invariant int counts."""
     Xf = X.astype(jnp.float32)
-    onehot = (assign[:, None] == jnp.arange(k0, dtype=jnp.int32)[None, :]
-              ).astype(jnp.float32)
     D_T = topo.fsum_blocks(
-        lambda xb, ob: jnp.matmul(xb.T, ob, precision=HIGHEST), Xf, onehot)
+        lambda xb, ab: _seg_sum_T(xb, ab, k0), Xf, assign)
     cnt = topo.isum(jax.ops.segment_sum(jnp.ones(assign.shape, jnp.int32),
                                         assign, num_segments=k0))
     return D_T, cnt.astype(jnp.float32)

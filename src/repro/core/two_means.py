@@ -138,13 +138,16 @@ def two_means_tree(X: jax.Array, k: int, key: jax.Array,
 #   seeds     two random members per cluster, picked by a per-level salted
 #             integer hash of the GLOBAL row id (min-hash with row-id
 #             tie-break — min reductions are order-invariant, so the psum
-#             combine is exact); their vectors are recovered with an
-#             owner-masked (d, k) matmul whose psum reduces owner + zeros.
+#             combine is exact); their vectors are gathered from the owning
+#             shard's rows (zeros elsewhere), and the psum reduces owner +
+#             zeros.
 #   refine    plain 2-means Lloyd steps on the discriminant sign (the paper
 #             runs 2-means first and adjusts to equal size after); per-
-#             cluster sums travel transposed as (d, k) per-shard partials
-#             combined in FIXED shard order (all-gather + ordered sum), so
-#             both topologies add the same blocks in the same order.
+#             cluster sums are O(B * d) segment sums (``_seg_sum_T``, never a
+#             one-hot matmul over all k leaves) that travel transposed as
+#             (d, k) per-shard partials combined in FIXED shard order
+#             (all-gather + ordered sum), so both topologies add the same
+#             blocks in the same order.
 #   split     the paper's "adjust to equal size": an EXACT distributed
 #             median — 8-round radix select over the composite 64-bit key
 #             (monotone-u32(delta) ‖ row id) using (k, 256) int32 histogram
@@ -227,6 +230,11 @@ class _TreeTopo:
         return x
 
 
+def _seg_sum_T(rows, seg, k):
+    """Per-cluster sums of rows (B, d) by seg (B,), transposed to (d, k)."""
+    return jax.ops.segment_sum(rows, seg, num_segments=k).T
+
+
 def _radix_left(ukey, pos_u, seg, k, r, active, topo: _TreeTopo):
     """Exact per-cluster rank select: mark the r[c] smallest composite keys.
 
@@ -272,6 +280,16 @@ def _seed_pos(h, pos_u, seg, k, topo: _TreeTopo, exclude=None):
     return topo.seg_min(cand, seg, k)
 
 
+def _seed_rows_T(Xf, pos_u, pos_c):
+    """This shard's share of the seed rows, transposed to (d, k): row
+    ``pos_c[c]`` where this shard owns it, zeros elsewhere.  A shard's rows
+    are the contiguous global ids ``pos_u[0] + [0, B)``."""
+    idx = pos_c - pos_u[0]
+    owned = idx < jnp.uint32(Xf.shape[0])    # a negative offset wraps high
+    rows = Xf[jnp.where(owned, idx, 0).astype(jnp.int32)]      # (k, d)
+    return jnp.where(owned[:, None], rows, 0.0).T
+
+
 def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
                    key: jax.Array, *, shards: int = 1, data_axes=None,
                    refine_iters: int = 4) -> jax.Array:
@@ -297,19 +315,11 @@ def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
     if levels == 0:
         return jnp.zeros(row_ids.shape, jnp.int32)
 
-    def seed_vec_T(pos_c):
-        mask = (pos_u[:, None] == pos_c[None, :]).astype(jnp.float32)
-        return topo.owner_fsum(
-            jnp.matmul(Xf.T, mask, precision=HIGHEST))       # (d, k)
-
     def level(seg, lvl):
         m = jnp.int32(n_glob) >> lvl
         half = m >> 1
-        onehot = (seg[:, None] == jnp.arange(k, dtype=jnp.int32)[None, :]
-                  ).astype(jnp.float32)                      # (B, k)
         tot_T = topo.fsum_blocks(
-            lambda xb, ob: jnp.matmul(xb.T, ob, precision=HIGHEST),
-            Xf, onehot)
+            lambda xb, sb: _seg_sum_T(xb, sb, k), Xf, seg)    # (d, k)
         cntc = topo.seg_isum(jnp.ones(seg.shape, jnp.int32), seg, k)
 
         kl = jax.random.fold_in(key, lvl)
@@ -317,7 +327,8 @@ def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
         pos1 = _seed_pos(_mix32(pos_u ^ salts[0]), pos_u, seg, k, topo)
         pos2 = _seed_pos(_mix32(pos_u ^ salts[1]), pos_u, seg, k, topo,
                          exclude=pos1)
-        c1_T, c2_T = seed_vec_T(pos1), seed_vec_T(pos2)
+        c1_T = topo.owner_fsum(_seed_rows_T(Xf, pos_u, pos1))
+        c2_T = topo.owner_fsum(_seed_rows_T(Xf, pos_u, pos2))
 
         def delta_of(c1_T, c2_T):
             # ||x-c1||² - ||x-c2||² = 2 x.(c2-c1) + ||c1||² - ||c2||²;
@@ -339,8 +350,8 @@ def two_means_dist(X_loc: jax.Array, row_ids: jax.Array, k: int,
             w = _radix_left(ukey, pos_u, seg, k, r_half, all_rows, topo
                             ).astype(jnp.float32)
             s1_T = topo.fsum_blocks(
-                lambda xb, ob, wb: jnp.matmul(xb.T, ob * wb[:, None],
-                                              precision=HIGHEST), Xf, onehot, w)
+                lambda xb, sb, wb: _seg_sum_T(xb * wb[:, None], sb, k),
+                Xf, seg, w)
             n1 = topo.seg_isum(w.astype(jnp.int32), seg, k)
             n1f = jnp.maximum(n1, 1).astype(jnp.float32)
             n2f = jnp.maximum(cntc - n1, 1).astype(jnp.float32)
